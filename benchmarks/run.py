@@ -128,8 +128,11 @@ def main() -> None:
         else f"BENCH_{budget}_{args.only}.json"
     )
 
+    from repro.runtime import enable_compile_cache
+
     from . import common
 
+    enable_compile_cache()
     common.reset_results()
     base = QUICK_MODULES if args.quick else MODULES
     mods = [m for m in base if args.only is None or args.only in m]

@@ -116,7 +116,7 @@ def chunk_fingerprints(
     Entries past ``count`` have fp = 0 and length = 0.
 
     ``fp_impl="pallas"`` dispatches to the fused kernel
-    (kernels/fingerprint.py, interpret mode auto-selected on CPU) —
+    (kernels/fingerprint.py, interpreted on the CPU, compiled on a TPU) —
     bit-identical output, no per-byte gather/scatter.
     """
     if fp_impl == "pallas":

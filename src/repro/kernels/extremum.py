@@ -34,7 +34,7 @@ def block_max_pallas(
     *,
     block: int = DEFAULT_BLOCK,
     tile_blocks: int = DEFAULT_TILE_BLOCKS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Per-block maxima of a 1-D uint8 stream; pads tail with 0 (neutral)."""
     assert data.ndim == 1

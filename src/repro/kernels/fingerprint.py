@@ -6,38 +6,35 @@ a random gather from the 64 Ki-entry power table, and two ``segment_sum``
 scatter-adds.  This kernel removes every per-byte gather/scatter with an
 algebraic refactor of the polynomial hash
 
-    h_r(chunk) = sum_i b_i * r^(len-1-i)   mod p,   p = 2^31 - 1.
+    h_r(chunk) = sum_i b_i * r^(len-1-i)   mod p,   p = 2^31 - 1,
+               = (P_r(e) - P_r(s)) * r^(e-1) mod p,
+    P_r(i)     = sum_{j<i} b_j * r^-j            (negative exponents via the
+                                                  Fermat inverse, p prime)
 
-For a byte at stream index ``i = t0 + q`` (tile start ``t0``, lane ``q``)
-in a chunk with exclusive end ``e``, the needed power splits as
+for a chunk ``[s, e)``.  The grid walks one stream's tiles in order
+(``kernels/lanes.py`` layout: a tile is ``(TILE // 128, 128)`` byte lanes)
+and per tile, for both generators in one pass:
 
-    r^(e-1-i) = r^(TILE-1-q) * r^(e - t0 - TILE)
+1. ``w[q] = b[q] * r^-q`` — the 8-conditional-rotation byte mulmod against
+   a *fixed per-lane weight table* (the same VMEM block every grid step: no
+   per-byte table gather), summed per 1024-byte group into an SMEM prefix
+   table;
+2. for every chunk whose end falls in this tile (an index range computed
+   by the wrapper with one ``searchsorted`` per tile), ``P(e)`` from the
+   cross-tile carry ``P(t0)``, the group table and one masked in-group
+   reduction, the factor ``r^(e-1) = r^t0 * r^(e-1-t0)`` from a resident
+   power table, and the fingerprint against the latched ``P(s)`` of the
+   previous chunk, written with a masked store.
 
-so per tile the kernel computes, for both generators in one pass:
+Output is bit-identical to ``chunk_fingerprints(..., fp_impl="reference")``
+and to ``fingerprints_numpy`` — tests/test_fingerprint_kernel.py and the
+scheduler's first-dispatch cross-check (docs/KERNELS.md) enforce it;
+tests/test_tpu_compile.py compiles it for a TPU v5e.
 
-1. ``w[q] = b[q] * r^(TILE-1-q)`` — the 8-conditional-rotation byte mulmod
-   against a *fixed per-lane weight vector* (the same VMEM block every grid
-   step: no per-byte table gather);
-2. an in-kernel segmented mod-p reduction: 16-bit-limb cumulative sums of
-   ``w`` (exact for TILE <= 65536) read back at the tile-clipped chunk
-   starts/ends — two tiny per-chunk gathers instead of an n-element
-   scatter-add;
-3. the per-chunk rescale by ``r^(e - t0 - TILE)`` via a precomputed factor
-   table ``ftab[k] = r^(k-TILE)`` (negative exponents through the Fermat
-   inverse — p is prime), a 31-rotation general mulmod on a
-   ``(max_chunks,)`` vector.
-
-Per-tile partials are combined across the grid by the same limb-fold (the
-only work left outside the kernel, ``O(num_tiles * max_chunks)``).  Output
-is bit-identical to ``chunk_fingerprints(..., fp_impl="reference")`` and to
-``fingerprints_numpy`` — tests/test_fingerprint_kernel.py and the
-scheduler's first-dispatch cross-check (docs/KERNELS.md) enforce it.
-
-Constraints: TILE must be a multiple of 1024 (whole (8,128) VPU tiles) and
-<= 65536 (the limb-sum overflow bound); chunk lengths <= MAX_CHUNK = 65536
-(the power/factor-table bound, same as the reference); streams < 2 GiB —
-int32 byte positions, the same cap as the reference path (the cross-tile
-limb bound of TILE * 65536 tiles is looser and never binds).
+Constraints: TILE a multiple of 4096 (whole uint8 ``(32, 128)`` tiles);
+chunk lengths <= MAX_CHUNK = 65536 (the power-table bound, same as the
+reference); streams < 2 GiB — int32 byte positions, the same cap as the
+reference path.
 """
 from __future__ import annotations
 
@@ -47,73 +44,48 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.dedup.fingerprint import (
-    MAX_CHUNK,
-    R1,
-    R2,
-    _addmod,
-    _byte_mulmod,
-    _fold32,
-    _mulmod,
-    _pow_table_np,
-    _rot31,
-)
+from . import lanes
+from .lanes import GROUP, LANES, P31, addmod, mulmod
 
-DEFAULT_TILE = 64 * 1024  # == MAX_CHUNK: the largest exact-limb tile
+DEFAULT_TILE = 64 * 1024
 
 
-@functools.lru_cache(maxsize=None)
-def _weight_table_np(r: int, tile: int) -> np.ndarray:
-    """w[q] = r^(tile-1-q) mod p — the fixed per-lane weight vector."""
-    assert tile <= MAX_CHUNK, tile
-    return np.ascontiguousarray(_pow_table_np(r)[:tile][::-1])
+def _fp_kernel(tsc_ref, x_ref, bounds_ref, wneg_ref, postab_ref, fps_ref,
+               st_ref, gp_ref, w_ref, *, tile: int):
+    t0 = tsc_ref[0, 0]  # tile start offset in the stream
+    rneg = (tsc_ref[0, 1], tsc_ref[0, 2])  # r^-t0
+    rpos = (tsc_ref[0, 3], tsc_ref[0, 4])  # r^t0
+    c_lo, c_hi = tsc_ref[0, 5], tsc_ref[0, 6]  # chunks ending in this tile
+    hl = lanes.HashLanes(w_ref, gp_ref, tile // LANES)
 
+    @pl.when(t0 == 0)  # first tile: P(0) carry and P(s) latch are zero
+    def _init():
+        for i in range(4):
+            st_ref[i] = jnp.int32(0)
+        fps_ref[...] = jnp.zeros(fps_ref.shape, jnp.int32)
 
-@functools.lru_cache(maxsize=None)
-def _factor_table_np(r: int, tile: int) -> np.ndarray:
-    """ftab[k] = r^(k - tile) mod p for k in [0, tile + MAX_CHUNK).
+    hl.fill(x_ref[...].astype(jnp.int32), wneg_ref)
+    carry = (st_ref[0], st_ref[1])
 
-    Indexed by ``end - t0`` clipped into range: a chunk intersecting the
-    tile has ``t0 < end <= start + MAX_CHUNK < t0 + tile + MAX_CHUNK``.
-    Negative exponents go through the Fermat inverse (p = 2^31 - 1 is
-    prime, so r^-1 = r^(p-2)).
-    """
-    p = (1 << 31) - 1
-    out = np.empty(tile + MAX_CHUNK, dtype=np.uint32)
-    out[tile:] = _pow_table_np(r)
-    inv = pow(r, p - 2, p)
-    acc = 1
-    for d in range(1, tile + 1):
-        acc = (acc * inv) % p
-        out[tile - d] = acc
-    return out
+    def body(c, ps):
+        e = lanes.read_slot(bounds_ref, c)  # t0 < e <= t0 + tile
+        pe = []
+        for g in range(2):
+            pe.append(addmod(carry[g],
+                             mulmod(rneg[g], hl.prefix(g, e - t0), 31)))
+            rfac = mulmod(rpos[g],
+                          lanes.read_slot(postab_ref, e - 1 - t0, g), 31)
+            lanes.write_slot(fps_ref, c,
+                             mulmod(addmod(pe[g], P31 - ps[g]), rfac, 31), g)
+        return tuple(pe)
 
-
-def _fp_kernel(t0_ref, x_ref, bounds_ref, starts_ref, wpow_ref, ftab_ref,
-               out_ref, *, tile: int):
-    t0 = t0_ref[0, 0]  # tile start offset in the stream
-    x = x_ref[...].astype(jnp.uint32)  # (tile,) bytes
-    bounds = bounds_ref[...]  # (mc,) int32 exclusive ends, sentinel-padded
-    starts = starts_ref[...]  # (mc,) int32 chunk starts
-    # tile-local byte ranges [s, e) of each chunk (empty when disjoint)
-    e = jnp.clip(bounds - t0, 0, tile)
-    s = jnp.minimum(jnp.clip(starts - t0, 0, tile), e)
-    fidx = jnp.clip(bounds - t0, 0, ftab_ref.shape[-1] - 1).astype(jnp.int32)
-
-    def prefix(c, k):  # sum of the first k elements of an inclusive cumsum
-        return jnp.where(k > 0, c[jnp.maximum(k - 1, 0)], 0)
-
-    cols = []
+    ps = jax.lax.fori_loop(c_lo, c_hi, body, (st_ref[2], st_ref[3]))
     for g in range(2):
-        w = _byte_mulmod(x, wpow_ref[g])  # (tile,) < p, no per-byte gather
-        lo = jnp.cumsum(w & 0xFFFF, dtype=jnp.uint32)  # exact: tile <= 2^16
-        hi = jnp.cumsum(w >> 16, dtype=jnp.uint32)
-        lo_m = _fold32(prefix(lo, e) - prefix(lo, s))
-        hi_m = _fold32(prefix(hi, e) - prefix(hi, s))
-        partial = _addmod(lo_m, _rot31(hi_m, 16))  # segmented sum mod p
-        cols.append(_mulmod(ftab_ref[g, fidx], partial, 31))
-    out_ref[...] = jnp.stack(cols, axis=-1)[None]
+        st_ref[g] = addmod(
+            carry[g], mulmod(rneg[g], hl.total(g, tile // GROUP), 31))
+        st_ref[2 + g] = ps[g]
 
 
 @functools.partial(
@@ -126,7 +98,7 @@ def fingerprint_pallas(
     *,
     max_chunks: int,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-chunk (fp (max_chunks, 2) uint32, lengths (max_chunks,) int32).
 
@@ -138,46 +110,51 @@ def fingerprint_pallas(
     if n == 0:
         return (jnp.zeros((max_chunks, 2), jnp.uint32),
                 jnp.zeros((max_chunks,), jnp.int32))
-    tile = min(tile, max(1024, ((n + 1023) // 1024) * 1024))
-    assert tile % 1024 == 0 and tile <= MAX_CHUNK, tile
-    n_pad = (n + tile - 1) // tile * tile
-    nt = n_pad // tile
-    assert nt <= (1 << 16), (n, tile)  # cross-tile limb-sum exactness
-    x = jnp.pad(data.astype(jnp.uint8), (0, n_pad - n))
+    quantum = lanes.BYTE_ROWS * LANES
+    tile = max(quantum, min(lanes.round_up(tile, quantum),
+                            lanes.round_up(n, quantum)))
+    nt = (n + tile - 1) // tile
+    R = tile // LANES
+    x = lanes.as_rows(data.astype(jnp.uint8), nt * R)
     b32 = bounds.astype(jnp.int32)
     starts32 = jnp.concatenate([jnp.zeros((1,), jnp.int32), b32[:-1]])
-    t0s = (jnp.arange(nt, dtype=jnp.int32) * tile).reshape(nt, 1)
-    wpow = jnp.stack(
-        [jnp.asarray(_weight_table_np(r, tile)) for r in (R1, R2)]
-    )
-    ftab = jnp.stack(
-        [jnp.asarray(_factor_table_np(r, tile)) for r in (R1, R2)]
-    )
-
-    parts = pl.pallas_call(
-        functools.partial(_fp_kernel, tile=tile),
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),  # t0 (not program_id:
-            # stays correct when the whole call is vmapped over a batch)
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((max_chunks,), lambda i: (0,)),
-            pl.BlockSpec((max_chunks,), lambda i: (0,)),
-            pl.BlockSpec((2, tile), lambda i: (0, 0)),
-            pl.BlockSpec((2, tile + MAX_CHUNK), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, max_chunks, 2), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, max_chunks, 2), jnp.uint32),
-        interpret=interpret,
-    )(t0s, x, b32, starts32, wpow, ftab)
-
-    # cross-tile combine: per-tile partials < p, limb sums exact for nt <= 2^16
-    lo = jnp.sum(parts & 0xFFFF, axis=0, dtype=jnp.uint32)
-    hi = jnp.sum(parts >> 16, axis=0, dtype=jnp.uint32)
-    fp = _addmod(_fold32(lo), _rot31(_fold32(hi), 16))
-
-    lengths = b32 - starts32  # same masked tail as the reference path
+    mcr = lanes.round_up(max_chunks, GROUP) // LANES
+    wneg, postab = lanes.hash_tables(R)
+    # per-tile scalars: t0, r^-t0, r^t0, and the range [c_lo, c_hi) of
+    # valid chunks whose exclusive end falls in (t0, t0 + tile]
     valid = jnp.arange(max_chunks) < count
+    key = jnp.where(valid, b32, jnp.int32(2**31 - 1))
+    tile_ends = (np.arange(1, nt + 1) * tile).astype(np.int32)
+    c_hi = jnp.searchsorted(key, tile_ends, side="right").astype(jnp.int32)
+    c_lo = jnp.concatenate([jnp.zeros((1,), jnp.int32), c_hi[:-1]])
+    tsc = (jnp.asarray(lanes.tile_scalars(nt, tile))
+           .at[:, 0, 5].set(c_lo).at[:, 0, 6].set(c_hi))
+
+    fps = pl.pallas_call(
+        functools.partial(_fp_kernel, tile=tile),
+        grid=(nt,),  # sequential: the prefix carry threads through tiles
+        in_specs=[
+            # per-tile scalars as operands, not program_id: stays correct
+            # when the whole call is vmapped over a batch
+            pl.BlockSpec((None, 1, 8), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((R, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((mcr, LANES), lambda i: (0, 0)),
+            pl.BlockSpec((2, R, LANES), lambda i: (0, 0, 0)),
+            pl.BlockSpec((2, R, LANES), lambda i: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((2, mcr, LANES), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((2, mcr, LANES), jnp.int32),
+        scratch_shapes=[
+            pltpu.SMEM((4,), jnp.int32),  # P(t0) carry x2, P(s) latch x2
+            pltpu.SMEM((2 * (R // 8 + 1),), jnp.int32),  # group prefixes
+            pltpu.VMEM((2, R, LANES), jnp.int32),  # hash weights
+        ],
+        interpret=interpret,
+    )(tsc, x, lanes.as_rows(b32, mcr), wneg, postab)
+
+    fp = lanes.unrows(fps, max_chunks).T.astype(jnp.uint32)
+    lengths = b32 - starts32  # same masked tail as the reference path
     fp = jnp.where(valid[:, None], fp, 0)
     lengths = jnp.where(valid, lengths, 0)
     return fp, lengths

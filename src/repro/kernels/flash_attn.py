@@ -82,7 +82,7 @@ def flash_attention_pallas(
     causal: bool = True,
     q_block: int = 256,
     kv_block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Causal flash attention.  q/k/v: (B, S, H, hd) with equal H (repeat-KV
     upstream for GQA).  Returns (B, S, H, hd)."""

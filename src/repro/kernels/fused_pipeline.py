@@ -6,21 +6,24 @@ phase-2 boundary-selection scan, and the fingerprint kernel — the last of
 which re-reads every byte the mask pass already touched.  SeqCDC's
 throughput argument (and the follow-up AVX vector-chunking paper) is that
 boundary detection and hashing should share one pass over the data; this
-kernel is that fusion: per (row, tile) grid step the TILE-byte VMEM block
-is read **once** and feeds
+kernel is that fusion: per (row, tile) grid step the TILE-byte block (plus
+a halo from the next tile) is read **once** as ``(rows, 128)`` lanes
+(``kernels/lanes.py``) and feeds
 
-1. the mask comparison lanes — shifted pairwise compares over the tile plus
-   an (L-1)-byte halo, AND-reduced into the candidate bitmap, one opposite
-   compare for the opposing bitmap (identical decisions to
-   ``core/masks.py`` / ``kernels/seqcdc_masks.py``);
-2. the limb-accumulating hash state — per-byte weights against a *fixed*
-   per-lane ``r^-q`` vector (8 conditional 31-bit rotations, no per-byte
-   gather), 16-bit-limb cumulative sums exact for ``tile + halo <= 65536``;
-3. the boundary automaton — a ``fori_loop`` over the tile's W-byte blocks
-   running the exact ``_scan_wide`` step (it calls
-   ``core/automaton._resolve`` itself), with the scan state carried across
-   tiles in VMEM scratch (the grid iterates row-major, tiles innermost,
-   like the flash-attention kernel's kv state).
+1. the mask comparison lanes — shifted pairwise compares AND-reduced into
+   the candidate bitmap, one opposite compare for the opposing bitmap
+   (identical decisions to ``core/masks.py`` / ``kernels/seqcdc_masks.py``),
+   stored with a running count of opposing bits for the automaton;
+2. the hash lanes — per-byte weights against a *fixed* per-lane ``r^-q``
+   table (8 conditional 31-bit rotations, no per-byte gather) and a
+   per-1024-byte group prefix table in SMEM;
+3. the boundary automaton — a scalar ``fori_loop`` over the tile's W-byte
+   blocks running the exact ``_scan_wide`` step (it calls
+   ``core/automaton._resolve`` itself): each block loads the one ``(8,
+   128)`` register group that holds it and reduces the first candidate,
+   the skip trigger and the opposing count with masked min/sum reductions.
+   The scan state lives in SMEM scratch across tiles (the grid iterates
+   row-major, tiles innermost).
 
 Boundary decisions are consumed *in-kernel* to segment the hash reduction:
 the moment a block emits a chunk end ``e``, the fingerprint of ``[s, e)``
@@ -31,27 +34,27 @@ is read off the running prefix state —
                                                 bytes; negative exponents via
                                                 the Fermat inverse, p prime)
 
-— two scalar prefix reads, one factor gather, three 31-rotation mulmods.
-``P_r(s)`` was latched when the previous boundary was emitted, and the
-cross-tile carry ``P_r(t0)`` lives in scratch, so chunks spanning any
-number of tiles cost the same as local ones.  The final file-end boundary
-fixup of ``select_boundaries`` is replicated in-kernel at the last tile
-(``r^(n-1)`` arrives as a host-precomputed operand).
+— one group load and masked sum for ``P_r(e)``, one for the factor, three
+31-rotation mulmods.  ``P_r(s)`` was latched when the previous boundary was
+emitted, and the cross-tile carry ``P_r(t0)`` lives in SMEM, so chunks
+spanning any number of tiles cost the same as local ones.  Outputs are
+written with masked stores into the row's resident ``(rows, 128)`` output
+blocks.  The final file-end boundary fixup of ``select_boundaries`` is
+replicated in-kernel at the last tile (``r^(n-1)`` is a host operand).
 
 Output is bit-identical to the composed split path — bounds/count from
 ``boundaries_batch(step_impl="wide")`` and fps/lengths from
 ``chunk_fingerprints`` — which tests/test_fused_pipeline.py, the
 differential matrix harness (tests/test_pipeline_matrix.py), and the
 scheduler's first-dispatch ``PipelineDivergenceError`` cross-check
-(docs/KERNELS.md) all enforce.
+(docs/KERNELS.md) all enforce; tests/test_tpu_compile.py compiles it for a
+TPU v5e.
 
-Constraints: TILE a multiple of 1024 (whole (8,128) VPU tiles) with
-``TILE + halo <= 65536`` where ``halo = skip_size + seq_length - 1``
-(the limb-sum exactness bound; the halo is that wide because an
-overshooting skip resolved as a cut can emit a bound ``skip_size + L - 1``
-bytes past its block — hence the 32 KiB default, half the fingerprint
-kernel's); chunk lengths <= ``MAX_CHUNK`` = 65536 (the power-table bound,
-as everywhere); streams < 2 GiB (int32 positions).
+Constraints: TILE a multiple of the halo block (a power of two of at least
+4096 bytes holding ``skip_size + seq_length - 1`` bytes: an overshooting
+skip resolved as a cut can emit a bound that far past its block);
+``seq_length <= 128``; chunk lengths <= ``MAX_CHUNK`` = 65536 (the
+power-table bound, as everywhere); streams < 2 GiB (int32 positions).
 """
 from __future__ import annotations
 
@@ -60,189 +63,254 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.automaton import _BIG, _resolve
 from repro.core.params import SeqCDCParams
-from repro.dedup.fingerprint import (
-    MAX_CHUNK,
-    P31,
-    R1,
-    R2,
-    _addmod,
-    _byte_mulmod,
-    _fold32,
-    _mulmod,
-    _pow_table_np,
-    _rot31,
-)
+from repro.dedup.fingerprint import MAX_CHUNK, R1, R2, _pow_table_np
+
+from . import lanes
+from .lanes import GROUP, LANES, P31, addmod, mulmod
 
 #: selects the scheduler's device pipeline: three dispatches ("split" —
 #: masks, boundary scan, fingerprints) or this kernel ("fused")
 PipelineImpl = Literal["split", "fused"]
 
-DEFAULT_TILE = 32 * 1024  # + halo stays under the 65536 limb-exactness bound
+DEFAULT_TILE = 32 * 1024
 
 
-@functools.lru_cache(maxsize=None)
-def _negpow_table_np(r: int, size: int) -> np.ndarray:
-    """w[q] = r^-q mod p — the fixed per-lane prefix weight vector."""
-    p = (1 << 31) - 1
-    inv = pow(r, p - 2, p)  # Fermat: p is prime
-    out = np.empty(size, dtype=np.uint32)
-    acc = 1
-    for q in range(size):
-        out[q] = acc
-        acc = (acc * inv) % p
-    return out
+def _geometry(n: int, p: SeqCDCParams, tile: int):
+    """(tile, nt, halo_rows, nb_split) for an ``n``-byte row.
+
+    The split automaton pads its bitmaps so every event fires in-scan
+    (``core/automaton._padded_blocks``): the grid covers exactly those
+    ``nb_split`` blocks.  The halo block holds the bytes a mask compare or
+    an emitted bound can reach past the tile (``skip_size + L - 1``).
+    """
+    if p.seq_length > LANES:
+        raise ValueError(f"seq_length {p.seq_length} exceeds {LANES}")
+    W = p.block_width
+    hrows = lanes.halo_rows(p.skip_size + p.seq_length - 1)
+    quantum = hrows * LANES
+    nb_split = (n + p.skip_size + W + W - 1) // W
+    cover = nb_split * W
+    tile = max(quantum, min(lanes.round_up(tile, quantum),
+                            lanes.round_up(cover, quantum)))
+    assert tile % W == 0 and tile % GROUP == 0, (tile, W)
+    nt = (cover + tile - 1) // tile
+    return tile, nt, hrows, nb_split
 
 
-def _mulmod31(a, y):
-    """a * y mod p for a, y < p — 31 conditional rotations (scalar use)."""
-    return _mulmod(a, y, 31)
+def _empty(B: int, mc: int):
+    return (jnp.full((B, mc), _BIG, jnp.int32), jnp.zeros((B,), jnp.int32),
+            jnp.zeros((B, mc, 2), jnp.uint32), jnp.zeros((B, mc), jnp.int32))
+
+
+def _out_specs_shapes(B: int, mc: int):
+    mcr = lanes.round_up(mc, GROUP) // LANES
+    specs = [
+        pl.BlockSpec((None, mcr, LANES), lambda b, i: (b, 0, 0)),  # bounds
+        pl.BlockSpec((None, mcr, LANES), lambda b, i: (b, 0, 0)),  # lengths
+        pl.BlockSpec((None, 2, mcr, LANES), lambda b, i: (b, 0, 0, 0)),
+        pl.BlockSpec((None, 8, LANES), lambda b, i: (b, 0, 0)),  # count
+    ]
+    shapes = [
+        jax.ShapeDtypeStruct((B, mcr, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((B, mcr, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((B, 2, mcr, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((B, 8, LANES), jnp.int32),
+    ]
+    return specs, shapes
+
+
+def _unpack_outputs(bounds, lens, fps, counts, mc: int):
+    fps = jnp.swapaxes(lanes.unrows(fps, mc), 1, 2).astype(jnp.uint32)
+    return (lanes.unrows(bounds, mc), counts[:, 0, 0], fps,
+            lanes.unrows(lens, mc))
+
+
+def _byte_specs(R: int, hrows: int):
+    return [
+        pl.BlockSpec((None, R, LANES), lambda b, i: (b, i, 0)),
+        pl.BlockSpec((None, hrows, LANES),
+                     lambda b, i: (b, (i + 1) * (R // hrows), 0)),
+    ]
+
+
+def _table_specs(ext_rows: int):
+    return [pl.BlockSpec((2, ext_rows, LANES), lambda b, i: (0, 0, 0))] * 2
+
+
+class _Outputs:
+    """The row's resident output blocks, written one chunk at a time."""
+
+    def __init__(self, bounds_ref, lens_ref, fps_ref, counts_ref, mc: int):
+        self.bounds, self.lens, self.fps = bounds_ref, lens_ref, fps_ref
+        self.counts = counts_ref
+        self.mc = mc
+
+    def init(self):
+        self.bounds[...] = jnp.full(self.bounds.shape, _BIG, jnp.int32)
+        self.lens[...] = jnp.zeros(self.lens.shape, jnp.int32)
+        self.fps[...] = jnp.zeros(self.fps.shape, jnp.int32)
+        self.counts[...] = jnp.zeros(self.counts.shape, jnp.int32)
+
+    def put(self, cnt, bound, length, fp0, fp1):
+        """Chunk ``cnt`` (dropped past ``mc``: the split path's
+        ``mode="drop"`` scatter)."""
+        @pl.when(cnt < self.mc)
+        def _():
+            lanes.write_slot(self.bounds, cnt, bound)
+            lanes.write_slot(self.lens, cnt, length)
+            lanes.write_slot(self.fps, cnt, fp0, 0)
+            lanes.write_slot(self.fps, cnt, fp1, 1)
+
+    def last_bound(self, cnt):
+        return jnp.where(cnt > 0, lanes.read_slot(
+            self.bounds, jnp.clip(cnt - 1, 0, self.mc - 1)), 0)
+
+
+def _block_events(msk_ref, j: int, W: int, t0, k, c, T):
+    """(kc, kt, carry) of W-block ``j`` from the packed mask lanes.
+
+    ``msk = cand + 2 * opp + 4 * incl`` where ``incl`` is the tile's
+    inclusive opposing-bit count; a W-block (W <= 1024, a power of two)
+    never straddles a 1024-position group, so one register group holds it.
+    ``kc``: first candidate at or after ``k``; ``kt``: first opposing pair
+    whose running count (carry ``c`` plus active pairs so far) exceeds
+    ``T``; ``carry``: ``c`` plus the block's active opposing pairs.
+    """
+    start = j * W
+    v = msk_ref[lanes.group_rows(start), :]
+    q = lanes.group_iota()
+    off = start % GROUP
+    pos = t0 + (start - off) + q
+    act = (q >= off) & (q < off + W) & (pos >= k)
+    cb = (v & 1) == 1
+    ob = ((v >> 1) & 1) == 1
+    incl = v >> 2
+    kc = jnp.min(jnp.where(act & cb, pos, _BIG))
+    # exclusive count at the first active position (incl is nondecreasing)
+    base = jnp.min(jnp.where(act, incl - (v >> 1 & 1), _BIG))
+    oa = act & ob
+    kt = jnp.min(jnp.where(oa & (c + incl - base > T), pos, _BIG))
+    carry = c + jnp.sum(jnp.where(oa, 1, 0))
+    return kc, kt, carry
 
 
 def _pipeline_kernel(
-    t0_ref, x_ref, halo_ref, rneg_ref, rpos_ref, wneg_ref, postab_ref,
-    rnm1_ref, bounds_ref, counts_ref, fps_ref, lens_ref, sti_ref, sth_ref,
-    *, p: SeqCDCParams, n: int, mc: int, tile: int, halo: int,
+    tsc_ref, x_ref, halo_ref, wneg_ref, postab_ref,
+    bounds_ref, lens_ref, fps_ref, counts_ref,
+    st_ref, gp_ref, w_ref, msk_ref,
+    *, p: SeqCDCParams, n: int, mc: int, tile: int, hrows: int,
     nb_split: int, last_t0: int,
 ):
-    t0 = t0_ref[0, 0]  # tile start offset in the (padded) stream
+    t0 = tsc_ref[0, 0]  # tile start offset in the (padded) stream
+    rneg = (tsc_ref[0, 1], tsc_ref[0, 2])  # r^-t0
+    rpos = (tsc_ref[0, 3], tsc_ref[0, 4])  # r^t0
+    rnm1 = (tsc_ref[0, 5], tsc_ref[0, 6])  # r^(n-1)
     L = p.seq_length
     W = p.block_width
-    nb = tile // W
+    R = tile // LANES
+    ext_len = (R + hrows) * LANES
     T = jnp.int32(p.skip_trigger)
-    ext_len = tile + halo
+    out = _Outputs(bounds_ref, lens_ref, fps_ref, counts_ref, mc)
+    hl = lanes.HashLanes(w_ref, gp_ref, R + hrows)
 
     @pl.when(t0 == 0)  # first tile of a row: reset state and outputs
     def _init():
-        sti_ref[...] = jnp.zeros_like(sti_ref)  # k, c, s, cnt
-        sti_ref[0] = np.int32(p.sub_min_skip)
-        sth_ref[...] = jnp.zeros_like(sth_ref)  # P(t0) carry, P(s) latch
-        bounds_ref[...] = jnp.full_like(bounds_ref, _BIG)
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-        fps_ref[...] = jnp.zeros_like(fps_ref)
-        lens_ref[...] = jnp.zeros_like(lens_ref)
+        for i in range(8):  # k, c, s, cnt, P(t0) carry x2, P(s) latch x2
+            st_ref[i] = jnp.int32(p.sub_min_skip if i == 0 else 0)
+        out.init()
 
-    # -- the one byte read: tile + (L-1)-byte halo from the next tile -------
-    x = x_ref[0]  # (tile,) uint8
-    ext = jnp.concatenate([x, halo_ref[0, 0]])  # (tile + halo,)
+    # -- the one byte read: tile + halo from the next tile ------------------
+    ext = jnp.concatenate([x_ref[...].astype(jnp.int32),
+                           halo_ref[...].astype(jnp.int32)], axis=0)
 
     # -- mask lanes (phase 1, same decisions as core/masks.py) --------------
-    a = ext[:-1]
-    b = ext[1:]
-    gt = b > a  # (tile + halo - 1,) pair bits
-    lt = b < a
-    inc = p.mode == "increasing"
-    fwd = gt if inc else lt
-    acc = fwd[:tile]
-    for j in range(1, L - 1):  # AND of L-1 shifted pair masks
-        acc = jnp.logical_and(acc, fwd[j:j + tile])
-    pos = t0 + jnp.arange(tile, dtype=jnp.int32)
-    cand = acc & (pos <= n - L)  # the reference wrapper's tail masking
-    opp = (lt if inc else gt)[:tile] & (pos < n - 1)
+    cand, opp = lanes.mask_lanes(ext, R, L, p.mode == "increasing")
+    pos = t0 + lanes.flat_iota(R)
+    cand = cand & (pos <= n - L)  # the reference wrapper's tail masking
+    opp = (opp & (pos < n - 1)).astype(jnp.int32)
+    msk_ref[...] = (cand.astype(jnp.int32) + 2 * opp
+                    + 4 * lanes.prefix_sum_flat(opp))
 
-    # -- hash lanes: position-weighted limb prefix sums ---------------------
-    xw = ext.astype(jnp.uint32)
-    lo, hi = [], []
-    for g in range(2):
-        w = _byte_mulmod(xw, wneg_ref[g])  # b_q * r^-q, fixed weight vector
-        lo.append(jnp.cumsum(w & 0xFFFF, dtype=jnp.uint32))  # exact:
-        hi.append(jnp.cumsum(w >> 16, dtype=jnp.uint32))  # ext_len <= 2^16
-    rneg = rneg_ref[0]  # (2,) r^-t0
-    rpos = rpos_ref[0]  # (2,) r^t0
-    carry0 = sth_ref[0, 0]  # P(t0) per generator
-    carry1 = sth_ref[0, 1]
+    # -- hash lanes: position-weighted bytes and their group prefixes ------
+    hl.fill(ext, wneg_ref)
+    carry = (st_ref[4], st_ref[5])  # P(t0) per generator
 
-    def tile_prefix(g, m):
-        """P within this tile: sum of the first ``m`` ext weights, mod p."""
-        i = jnp.maximum(m - 1, 0)
-        part = _addmod(_fold32(lo[g][i]), _rot31(_fold32(hi[g][i]), 16))
-        return jnp.where(m > 0, part, jnp.uint32(0))
-
-    def prefix_at(g, carry_g, e):
+    def prefix_at(g, e):
         """P(e) for a stream position ``e`` inside [t0, t0 + ext_len]."""
         m = jnp.clip(e - t0, 0, ext_len)
-        return _addmod(carry_g, _mulmod31(rneg[g], tile_prefix(g, m)))
+        return addmod(carry[g], mulmod(rneg[g], hl.prefix(g, m), 31))
 
-    def chunk_fp(g, carry_g, ps_g, e):
+    def chunk_fp(g, ps_g, e):
         """(P(e) - P(s)) * r^(e-1): the fingerprint of the closing chunk."""
-        pe = prefix_at(g, carry_g, e)
-        diff = _addmod(pe, P31 - ps_g)  # canonical: both operands < p
+        pe = prefix_at(g, e)
+        diff = addmod(pe, P31 - ps_g)
         fi = jnp.clip(e - 1 - t0, 0, ext_len - 1)
-        rfac = _mulmod31(rpos[g], postab_ref[g, fi])
+        rfac = mulmod(rpos[g], lanes.read_slot(postab_ref, fi, g), 31)
         # a bound behind this tile is only ever the file-end cut (the scan
         # position can overshoot cut_k = n - L + 1 when the tail is shorter
         # than a skip landing); its factor r^(n-1) is the host operand —
         # prefix_at is already exact there, P(t0) == P(n) past the data
-        rfac = jnp.where(e - 1 - t0 < 0, rnm1_ref[0, g], rfac)
-        return pe, _mulmod31(diff, rfac)
+        rfac = jnp.where(e - 1 - t0 < 0, rnm1[g], rfac)
+        return pe, mulmod(diff, rfac, 31)
 
     # -- boundary automaton: the exact _scan_wide step per W-block ----------
-    iota = jnp.arange(W, dtype=jnp.int32)
-    k0, c0, s0, cnt0 = sti_ref[0], sti_ref[1], sti_ref[2], sti_ref[3]
-    ps0 = sth_ref[1, 0], sth_ref[1, 1]
-
     def body(j, st):
-        k, c, s, cnt, ps_0, ps_1 = st
+        k, c, s, cnt, ps0, ps1 = st
         bstart = t0 + j * W
         bend = bstart + W
         # blocks past the split path's padded bitmap simply don't exist
         # there; masking in_block reproduces that exactly
         in_block = (k < bend) & (s < n) & (t0 // W + j < nb_split)
-        cb = jax.lax.dynamic_slice(cand, (j * W,), (W,))
-        ob = jax.lax.dynamic_slice(opp, (j * W,), (W,))
-        o = jnp.maximum(k - bstart, 0)
-        active = iota >= o
-        posw = bstart + iota
-        kc = jnp.min(jnp.where(cb & active, posw, _BIG))
-        cum = c + jnp.cumsum((ob & active).astype(jnp.int32))
-        kt = jnp.min(jnp.where(ob & active & (cum > T), posw, _BIG))
+        kc, kt, cum_last = jax.lax.cond(
+            in_block,
+            lambda: _block_events(msk_ref, j, W, t0, k, c, T),
+            lambda: (jnp.int32(_BIG), jnp.int32(_BIG), c),
+        )
         new_k, new_s, emit, bound, any_event = _resolve(
             k, c, s, kc, kt, bend, in_block, n, p
         )
-        new_c = jnp.where(any_event, 0, jnp.where(in_block, cum[-1], c))
-        # boundary decision consumed in-kernel: segment the hash reduction
-        pe0, fp0 = chunk_fp(0, carry0, ps_0, bound)
-        pe1, fp1 = chunk_fp(1, carry1, ps_1, bound)
-        idx = jnp.minimum(cnt, mc - 1)
-        keep = emit & (cnt < mc)  # the split path's mode="drop" scatter
-        bounds_ref[0, idx] = jnp.where(keep, bound, bounds_ref[0, idx])
-        lens_ref[0, idx] = jnp.where(keep, bound - s, lens_ref[0, idx])
-        fps_ref[0, idx, 0] = jnp.where(keep, fp0, fps_ref[0, idx, 0])
-        fps_ref[0, idx, 1] = jnp.where(keep, fp1, fps_ref[0, idx, 1])
-        return (new_k, new_c, new_s, cnt + emit.astype(jnp.int32),
-                jnp.where(emit, pe0, ps_0), jnp.where(emit, pe1, ps_1))
+        new_c = jnp.where(any_event, 0, jnp.where(in_block, cum_last, c))
 
-    k, c, s, cnt, ps_0, ps_1 = jax.lax.fori_loop(
-        0, nb, body, (k0, c0, s0, cnt0, *ps0)
+        def emit_chunk():
+            pe0, fp0 = chunk_fp(0, ps0, bound)
+            pe1, fp1 = chunk_fp(1, ps1, bound)
+            out.put(cnt, bound, bound - s, fp0, fp1)
+            return cnt + 1, pe0, pe1
+
+        cnt, ps0, ps1 = jax.lax.cond(
+            emit, emit_chunk, lambda: (cnt, ps0, ps1))
+        return new_k, new_c, new_s, cnt, ps0, ps1
+
+    k, c, s, cnt, ps0, ps1 = jax.lax.fori_loop(
+        0, tile // W, body,
+        (st_ref[0], st_ref[1], st_ref[2], st_ref[3], st_ref[6], st_ref[7]),
     )
 
     # -- final-boundary fixup (select_boundaries' post-scan guarantee) ------
-    last = jnp.where(
-        cnt > 0, bounds_ref[0, jnp.clip(cnt - 1, 0, mc - 1)], 0)
-    need = (t0 == last_t0) & (last < n)  # n > 0: static in this kernel
-    pe0 = prefix_at(0, carry0, jnp.int32(n))  # r^(n-1) is a host operand:
-    fp0 = _mulmod31(_addmod(pe0, P31 - ps_0), rnm1_ref[0, 0])  # n - 1 may
-    pe1 = prefix_at(1, carry1, jnp.int32(n))  # fall outside this tile's
-    fp1 = _mulmod31(_addmod(pe1, P31 - ps_1), rnm1_ref[0, 1])  # factor table
-    idx = jnp.minimum(cnt, mc - 1)
-    keep = need & (cnt < mc)
-    bounds_ref[0, idx] = jnp.where(keep, jnp.int32(n), bounds_ref[0, idx])
-    lens_ref[0, idx] = jnp.where(keep, jnp.int32(n) - s, lens_ref[0, idx])
-    fps_ref[0, idx, 0] = jnp.where(keep, fp0, fps_ref[0, idx, 0])
-    fps_ref[0, idx, 1] = jnp.where(keep, fp1, fps_ref[0, idx, 1])
+    need = (t0 == last_t0) & (out.last_bound(cnt) < n)
+
+    @pl.when(need)
+    def _fixup():
+        fps = [mulmod(addmod(prefix_at(g, jnp.int32(n)), P31 - ps), rnm1[g],
+                      31) for g, ps in ((0, ps0), (1, ps1))]
+        out.put(cnt, jnp.int32(n), jnp.int32(n) - s, *fps)
+
     cnt = cnt + need.astype(jnp.int32)
 
     # -- persist state for the next tile ------------------------------------
-    counts_ref[0, 0] = cnt
-    sti_ref[...] = jnp.stack([k, c, s, cnt])
-    sth_ref[0, 0] = _addmod(carry0, _mulmod31(rneg[0], tile_prefix(0, tile)))
-    sth_ref[0, 1] = _addmod(carry1, _mulmod31(rneg[1], tile_prefix(1, tile)))
-    sth_ref[1, 0] = ps_0
-    sth_ref[1, 1] = ps_1
+    counts_ref[...] = jnp.full(counts_ref.shape, cnt, jnp.int32)
+    for i, v in enumerate((k, c, s, cnt)):
+        st_ref[i] = v
+    for g in range(2):
+        st_ref[4 + g] = addmod(
+            carry[g], mulmod(rneg[g], hl.total(g, tile // GROUP), 31))
+    st_ref[6] = ps0
+    st_ref[7] = ps1
 
 
 @functools.partial(
@@ -254,7 +322,7 @@ def fused_pipeline_batch(
     *,
     max_chunks: int,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Chunk + fingerprint a ``(B, S)`` uint8 batch in one dispatch.
 
@@ -275,99 +343,47 @@ def fused_pipeline_batch(
     B, n = data.shape
     mc = max_chunks
     if n == 0:  # static: no chunks, matching the split path's empty case
-        return (jnp.full((B, mc), _BIG, jnp.int32),
-                jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B, mc, 2), jnp.uint32),
-                jnp.zeros((B, mc), jnp.int32))
+        return _empty(B, mc)
     if p.max_size > MAX_CHUNK:
         raise ValueError(
             f"max_size {p.max_size} exceeds the fingerprint power-table "
             f"bound {MAX_CHUNK}"
         )
-    L = p.seq_length
-    W = p.block_width
-    # halo: the mask pair bits spill L-1 bytes past the tile, but emitted
-    # bounds spill further — an overshooting skip resolved as a cut
-    # (_resolve's trig_cuts) lands at cut_b < block_end + skip_size + L - 1,
-    # and the in-kernel prefix/factor reads at that bound must still be
-    # inside the extended byte window
-    halo = p.skip_size + L - 1
-    # the split automaton pads its bitmaps so every event fires in-scan
-    # (core/automaton._padded_blocks); cover exactly those blocks
-    nb_split = (n + p.skip_size + W + W - 1) // W
-    cover = nb_split * W
-    tile = min(tile, (cover + 1023) // 1024 * 1024)
-    assert tile % 1024 == 0 and tile % W == 0, (tile, W)
-    assert tile + halo <= MAX_CHUNK, (tile, halo)  # limb-sum exactness
-    nt = (cover + tile - 1) // tile
-    n_pad = nt * tile
+    tile, nt, hrows, nb_split = _geometry(n, p, tile)
+    R = tile // LANES
+    x = lanes.as_rows(data.astype(jnp.uint8), nt * R + hrows)
+    wneg, postab = lanes.hash_tables(R + hrows)
+    tsc = jnp.asarray(lanes.tile_scalars(
+        nt, tile, [pow(r, n - 1, int(P31)) for r in (R1, R2)]))
+    out_specs, out_shape = _out_specs_shapes(B, mc)
+    ext_rows = R + hrows
 
-    x = jnp.pad(data.astype(jnp.uint8), ((0, 0), (0, n_pad - n)))
-    # halos[b, i] = x[b, (i+1)*tile : (i+1)*tile + halo], zero past the end
-    # (halo may exceed tile when skip_size does, so slice rather than
-    # reshape; nt is small and static)
-    xh = jnp.pad(x, ((0, 0), (0, halo)))
-    halos = jnp.stack(
-        [xh[:, (i + 1) * tile:(i + 1) * tile + halo] for i in range(nt)],
-        axis=1,
-    )
-    t0s = (jnp.arange(nt, dtype=jnp.int32) * tile).reshape(nt, 1)
-
-    pm = (1 << 31) - 1
-    wneg = jnp.stack(
-        [jnp.asarray(_negpow_table_np(r, tile + halo)) for r in (R1, R2)]
-    )
-    postab = jnp.stack(
-        [jnp.asarray(_pow_table_np(r)[: tile + halo]) for r in (R1, R2)]
-    )
-    rneg = jnp.asarray(np.array(
-        [[pow(pow(r, pm - 2, pm), i * tile, pm) for r in (R1, R2)]
-         for i in range(nt)], dtype=np.uint32))
-    rpos = jnp.asarray(np.array(
-        [[pow(r, i * tile, pm) for r in (R1, R2)] for i in range(nt)],
-        dtype=np.uint32))
-    rnm1 = jnp.asarray(np.array(
-        [[pow(r, n - 1, pm) for r in (R1, R2)]], dtype=np.uint32))
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    bounds, counts, fps, lens = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(
-            _pipeline_kernel, p=p, n=n, mc=mc, tile=tile, halo=halo,
+            _pipeline_kernel, p=p, n=n, mc=mc, tile=tile, hrows=hrows,
             nb_split=nb_split, last_t0=(nt - 1) * tile,
         ),
         grid=(B, nt),  # row-major: each row's tiles run in order, so the
-        # scratch scan/hash state threads through them (re-init at t0 == 0)
+        # SMEM scan/hash state threads through them (re-init at t0 == 0)
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (i, 0)),  # t0 (operand, not
-            # program_id: the index map owns the grid->tile mapping)
-            pl.BlockSpec((1, tile), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1, halo), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 2), lambda b, i: (i, 0)),  # r^-t0
-            pl.BlockSpec((1, 2), lambda b, i: (i, 0)),  # r^t0
-            pl.BlockSpec((2, tile + halo), lambda b, i: (0, 0)),
-            pl.BlockSpec((2, tile + halo), lambda b, i: (0, 0)),
-            pl.BlockSpec((1, 2), lambda b, i: (0, 0)),  # r^(n-1)
+            # per-tile scalars as operands, not program_id: the index map
+            # owns the grid->tile mapping
+            pl.BlockSpec((None, 1, 8), lambda b, i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            *_byte_specs(R, hrows),
+            *_table_specs(ext_rows),
         ],
-        out_specs=[
-            pl.BlockSpec((1, mc), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, mc, 2), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, mc), lambda b, i: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, mc), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, mc, 2), jnp.uint32),
-            jax.ShapeDtypeStruct((B, mc), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((4,), jnp.int32),  # automaton k, c, s, cnt
-            pltpu.VMEM((2, 2), jnp.uint32),  # P(t0) carry, P(s) latch
+            pltpu.SMEM((8,), jnp.int32),  # automaton + hash registers
+            pltpu.SMEM((2 * (ext_rows // 8 + 1),), jnp.int32),  # group P
+            pltpu.VMEM((2, ext_rows, LANES), jnp.int32),  # hash weights
+            pltpu.VMEM((R, LANES), jnp.int32),  # packed mask lanes
         ],
         interpret=interpret,
-    )(t0s, x, halos, rneg, rpos, wneg, postab, rnm1)
-    return bounds, counts[:, 0], fps, lens
+    )(tsc, x, x, wneg, postab)
+    return _unpack_outputs(*outs, mc)
 
 
 def fused_pipeline(
@@ -376,7 +392,7 @@ def fused_pipeline(
     *,
     max_chunks: int,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Single-stream convenience: ``(n,)`` -> (bounds, count, fps, lengths)."""
     b, c, f, ln = fused_pipeline_batch(
@@ -391,10 +407,11 @@ def fused_pipeline(
 
 
 def _packed_pipeline_kernel(
-    t0_ref, x_ref, halo_ref, sep_ref, ends_ref, pend_ref, rend_ref,
-    rneg_ref, rpos_ref, wneg_ref, postab_ref,
-    bounds_ref, counts_ref, fps_ref, lens_ref, sti_ref, sth_ref, sps_ref,
-    *, p: SeqCDCParams, mc: int, tile: int, halo: int,
+    tsc_ref, x_ref, halo_ref, sep_ref, ends_ref, pend_ref, rend_ref,
+    wneg_ref, postab_ref,
+    bounds_ref, lens_ref, fps_ref, counts_ref,
+    st_ref, gp_ref, w_ref, wprev_ref, msk_ref,
+    *, p: SeqCDCParams, mc: int, tile: int, hrows: int,
     nb_split: int, last_t0: int,
 ):
     """``_pipeline_kernel`` with per-segment resets (docs/KERNELS.md).
@@ -402,7 +419,7 @@ def _packed_pipeline_kernel(
     Four deltas against the unpacked kernel:
 
     * the automaton's file end is the *current segment's* end ``se`` (a
-      fifth scratch register) instead of the static row width, and every
+      fifth scan register) instead of the static row width, and every
       emit landing on ``se`` advances it — the registers the emit leaves
       behind are exactly a fresh stream's init state, so the segment reset
       costs nothing beyond the extra register (the proof lives with
@@ -423,142 +440,124 @@ def _packed_pipeline_kernel(
       far behind) read host-shaped per-segment operands (``pend`` /
       ``rend``) looked up by end offset; max-size cuts land at most
       ``skip_size - L`` behind (a skip crossed the tile edge) and read the
-      ``sps`` scratch — the previous tile's last ``skip_size + 1`` prefix
-      values, stashed tile-to-tile — with ``r^(bound-1)`` reconstructed as
-      ``r^t0 * r^-(t0-bound+1)`` from the resident negpow table.
+      previous tile's hash lanes, kept one tile longer, with
+      ``r^(bound-1)`` reconstructed as ``r^t0 * r^-(t0-bound+1)`` from the
+      resident negpow table.
     """
-    t0 = t0_ref[0, 0]
+    t0 = tsc_ref[0, 0]
+    rneg = (tsc_ref[0, 1], tsc_ref[0, 2])
+    rpos = (tsc_ref[0, 3], tsc_ref[0, 4])
     L = p.seq_length
     W = p.block_width
-    nb = tile // W
+    R = tile // LANES
+    ext_rows = R + hrows
+    ext_len = ext_rows * LANES
+    HL = p.skip_size  # deepest behind-t0 reach of a max-size cut
     T = jnp.int32(p.skip_trigger)
-    ext_len = tile + halo
-    HL = p.skip_size  # left-stash depth: max behind-t0 reach of a max cut
-    ends = ends_ref[0]  # (G,) segment ends, padded with the payload end
+    out = _Outputs(bounds_ref, lens_ref, fps_ref, counts_ref, mc)
+    groups = ext_rows // 8
+    hl = lanes.HashLanes(w_ref, gp_ref, ext_rows)
+    hl_prev = lanes.HashLanes(wprev_ref, gp_ref, ext_rows,
+                              base=2 * (groups + 1))
+    ends = ends_ref[...]  # segment ends, zero-padded
     n_row = jnp.max(ends)  # dynamic payload end (0 for an all-pad row)
-    pend = pend_ref[0]  # (2, G) P(end) per generator
-    rend = rend_ref[0]  # (2, G) r^(end-1) per generator
 
     def next_end(x):
         return jnp.min(jnp.where(ends > x, ends, _BIG))
 
-    @pl.when(t0 == 0)  # first tile of a row: reset state and outputs
+    def end_lookup(tab_ref, g, e):
+        """The operand entry for the segment whose end == ``e``
+        (duplicate ends from empty segments carry identical values)."""
+        return jnp.max(jnp.where(ends == e, tab_ref[g], 0))
+
+    # scan registers: k, c, s, cnt, se; hash: P(t0) carry x2, P(s) latch
+    # x2; the previous tile's carry x2 and r^-t0 x2
+    @pl.when(t0 == 0)
     def _init():
-        sti_ref[...] = jnp.zeros_like(sti_ref)  # k, c, s, cnt, se
         first_end = next_end(jnp.int32(0))
+        for i in range(13):
+            st_ref[i] = jnp.int32(0)
         # same init clamp as _scan_wide_packed: the first segment may be
         # shorter than min_size
-        sti_ref[0] = jnp.minimum(jnp.int32(p.sub_min_skip),
-                                 first_end - (L - 1))
-        sti_ref[4] = first_end
-        sth_ref[...] = jnp.zeros_like(sth_ref)  # P(t0) carry, P(s) latch
-        sps_ref[...] = jnp.zeros_like(sps_ref)  # P(t0 - q) left stash
-        bounds_ref[...] = jnp.full_like(bounds_ref, _BIG)
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-        fps_ref[...] = jnp.zeros_like(fps_ref)
-        lens_ref[...] = jnp.zeros_like(lens_ref)
+        st_ref[0] = jnp.minimum(jnp.int32(p.sub_min_skip),
+                                first_end - (L - 1))
+        st_ref[4] = first_end
+        out.init()
 
-    # -- the one byte read: tile + halo, same as the unpacked kernel --------
-    x = x_ref[0]
-    ext = jnp.concatenate([x, halo_ref[0, 0]])
+    ext = jnp.concatenate([x_ref[...].astype(jnp.int32),
+                           halo_ref[...].astype(jnp.int32)], axis=0)
 
     # -- mask lanes, clipped per segment -------------------------------------
-    a = ext[:-1]
-    b = ext[1:]
-    gt = b > a
-    lt = b < a
-    inc = p.mode == "increasing"
-    fwd = gt if inc else lt
-    acc = fwd[:tile]
-    for j in range(1, L - 1):
-        acc = jnp.logical_and(acc, fwd[j:j + tile])
-    pos = t0 + jnp.arange(tile, dtype=jnp.int32)
-    sep = sep_ref[0]  # (tile,) exclusive end of each position's segment
-    cand = acc & (pos <= sep - L)
-    opp = (lt if inc else gt)[:tile] & (pos < sep - 1)
+    cand, opp = lanes.mask_lanes(ext, R, L, p.mode == "increasing")
+    pos = t0 + lanes.flat_iota(R)
+    sep = sep_ref[...]  # exclusive end of each position's segment
+    cand = cand & (pos <= sep - L)
+    opp = (opp & (pos < sep - 1)).astype(jnp.int32)
+    msk_ref[...] = (cand.astype(jnp.int32) + 2 * opp
+                    + 4 * lanes.prefix_sum_flat(opp))
 
-    # -- hash lanes: identical to the unpacked kernel ------------------------
-    xw = ext.astype(jnp.uint32)
-    lo, hi = [], []
-    for g in range(2):
-        w = _byte_mulmod(xw, wneg_ref[g])
-        lo.append(jnp.cumsum(w & 0xFFFF, dtype=jnp.uint32))
-        hi.append(jnp.cumsum(w >> 16, dtype=jnp.uint32))
-    rneg = rneg_ref[0]
-    rpos = rpos_ref[0]
-    carry0 = sth_ref[0, 0]
-    carry1 = sth_ref[0, 1]
+    hl.fill(ext, wneg_ref)
+    carry = (st_ref[5], st_ref[6])
+    carry_prev = (st_ref[9], st_ref[10])
+    rneg_prev = (st_ref[11], st_ref[12])
 
-    def tile_prefix(g, m):
-        i = jnp.maximum(m - 1, 0)
-        part = _addmod(_fold32(lo[g][i]), _rot31(_fold32(hi[g][i]), 16))
-        return jnp.where(m > 0, part, jnp.uint32(0))
-
-    def prefix_at(g, carry_g, e):
+    def prefix_at(g, e):
         m = jnp.clip(e - t0, 0, ext_len)
-        return _addmod(carry_g, _mulmod31(rneg[g], tile_prefix(g, m)))
+        return addmod(carry[g], mulmod(rneg[g], hl.prefix(g, m), 31))
 
-    def end_lookup(tab, g, e):
-        """The (2, G) operand entry for the segment whose end == ``e``
-        (duplicate ends from empty segments carry identical values)."""
-        return jnp.max(jnp.where(ends == e, tab[g], jnp.uint32(0)))
-
-    def chunk_fp(g, carry_g, ps_g, e):
+    def chunk_fp(g, ps_g, e):
         # a bound behind this tile is a cut: a segment end (pend/rend
         # operands, any depth) or a max-size cut a skip carried across the
-        # tile edge (< skip_size behind: the sps left stash, with the
-        # factor r^(e-1) = r^t0 * r^-(t0-e+1) off the negpow table)
+        # tile edge (< skip_size behind: the previous tile's hash lanes,
+        # with the factor r^(e-1) = r^t0 * r^-(t0-e+1) off the negpow table)
         behind = e - 1 - t0 < 0
-        is_end = jnp.any(ends == e)
-        pe_b = jnp.where(is_end, end_lookup(pend, g, e),
-                         sps_ref[g, jnp.clip(t0 - e, 0, HL)])
-        pe = jnp.where(behind, pe_b, prefix_at(g, carry_g, e))
-        diff = _addmod(pe, P31 - ps_g)
+        is_end = jnp.max(jnp.where(ends == e, 1, 0)) > 0
+        m_prev = jnp.clip(e - (t0 - tile), 0, ext_len)
+        pe_prev = addmod(carry_prev[g],
+                         mulmod(rneg_prev[g], hl_prev.prefix(g, m_prev), 31))
+        pe_b = jnp.where(is_end, end_lookup(pend_ref, g, e), pe_prev)
+        pe = jnp.where(behind, pe_b, prefix_at(g, e))
+        diff = addmod(pe, P31 - ps_g)
         fi = jnp.clip(e - 1 - t0, 0, ext_len - 1)
-        rfac = _mulmod31(rpos[g], postab_ref[g, fi])
+        rfac = mulmod(rpos[g], lanes.read_slot(postab_ref, fi, g), 31)
+        wi = jnp.clip(t0 - (e - 1), 0, HL + 1)
         rf_b = jnp.where(
-            is_end, end_lookup(rend, g, e),
-            _mulmod31(rpos[g], wneg_ref[g, jnp.clip(t0 - (e - 1), 0, HL + 1)]),
+            is_end, end_lookup(rend_ref, g, e),
+            mulmod(rpos[g], lanes.read_slot(wneg_ref, wi, g), 31),
         )
         rfac = jnp.where(behind, rf_b, rfac)
-        return pe, _mulmod31(diff, rfac)
+        return pe, mulmod(diff, rfac, 31)
 
     # -- packed boundary automaton: _scan_wide_packed's step per W-block -----
-    iota = jnp.arange(W, dtype=jnp.int32)
-    k0, c0, s0, cnt0, se0 = (sti_ref[0], sti_ref[1], sti_ref[2],
-                             sti_ref[3], sti_ref[4])
-    ps0 = sth_ref[1, 0], sth_ref[1, 1]
-
     def body(j, st):
         bstart = t0 + j * W
         bend = bstart + W
-        cb = jax.lax.dynamic_slice(cand, (j * W,), (W,))
-        ob = jax.lax.dynamic_slice(opp, (j * W,), (W,))
 
         def resolve_once(wst):
-            k, c, s, cnt, se, ps_0, ps_1, go = wst
+            k, c, s, cnt, se, ps0, ps1, _ = wst
             in_block = (k < bend) & (s < n_row) & (t0 // W + j < nb_split)
-            o = jnp.maximum(k - bstart, 0)
-            active = iota >= o
-            posw = bstart + iota
-            kc = jnp.min(jnp.where(cb & active, posw, _BIG))
-            cum = c + jnp.cumsum((ob & active).astype(jnp.int32))
-            kt = jnp.min(jnp.where(ob & active & (cum > T), posw, _BIG))
+            kc, kt, cum_last = jax.lax.cond(
+                in_block,
+                lambda: _block_events(msk_ref, j, W, t0, k, c, T),
+                lambda: (jnp.int32(_BIG), jnp.int32(_BIG), c),
+            )
             new_k, new_s, emit, bound, any_event = _resolve(
                 k, c, s, kc, kt, bend, in_block, se, p
             )
-            new_c = jnp.where(any_event, 0, jnp.where(in_block, cum[-1], c))
-            pe0, fp0 = chunk_fp(0, carry0, ps_0, bound)
-            pe1, fp1 = chunk_fp(1, carry1, ps_1, bound)
-            idx = jnp.minimum(cnt, mc - 1)
-            keep = emit & (cnt < mc)
-            bounds_ref[0, idx] = jnp.where(keep, bound, bounds_ref[0, idx])
-            lens_ref[0, idx] = jnp.where(keep, bound - s, lens_ref[0, idx])
-            fps_ref[0, idx, 0] = jnp.where(keep, fp0, fps_ref[0, idx, 0])
-            fps_ref[0, idx, 1] = jnp.where(keep, fp1, fps_ref[0, idx, 1])
-            # a bound on the segment end advances to the next segment: the
-            # emit's own register updates are the next stream's init state
-            new_se = jnp.where(emit & (bound >= se), next_end(bound), se)
+            new_c = jnp.where(any_event, 0, jnp.where(in_block, cum_last, c))
+
+            def emit_chunk():
+                pe0, fp0 = chunk_fp(0, ps0, bound)
+                pe1, fp1 = chunk_fp(1, ps1, bound)
+                out.put(cnt, bound, bound - s, fp0, fp1)
+                # a bound on the segment end advances to the next segment:
+                # the emit's own register updates are the next stream's
+                # init state
+                new_se = jnp.where(bound >= se, next_end(bound), se)
+                return cnt + 1, pe0, pe1, new_se
+
+            cnt, ps0, ps1, new_se = jax.lax.cond(
+                emit, emit_chunk, lambda: (cnt, ps0, ps1, se))
             # clamp the post-emit position to the next pending cut, exactly
             # as _scan_wide_packed does: the min-size skip may overleap a
             # run of tiny segments (and their end cuts) entirely
@@ -569,50 +568,53 @@ def _packed_pipeline_kernel(
             # re-resolve until the position clears it (_scan_wide_packed's
             # inner loop, block-for-block)
             go = emit & (new_k < bend) & (new_s < n_row)
-            return (new_k, new_c, new_s, cnt + emit.astype(jnp.int32),
-                    new_se, jnp.where(emit, pe0, ps_0),
-                    jnp.where(emit, pe1, ps_1), go)
+            return (new_k, new_c, new_s, cnt, new_se, ps0, ps1,
+                    go.astype(jnp.int32))
 
         wst = jax.lax.while_loop(
-            lambda wst: wst[-1], resolve_once, st + (jnp.bool_(True),)
+            lambda wst: wst[-1] > 0, resolve_once, st + (jnp.int32(1),)
         )
         return wst[:-1]
 
-    k, c, s, cnt, se, ps_0, ps_1 = jax.lax.fori_loop(
-        0, nb, body, (k0, c0, s0, cnt0, se0, *ps0)
+    k, c, s, cnt, se, ps0, ps1 = jax.lax.fori_loop(
+        0, tile // W, body,
+        (st_ref[0], st_ref[1], st_ref[2], st_ref[3], st_ref[4], st_ref[7],
+         st_ref[8]),
     )
 
     # -- final-boundary fixup: the row's payload end, dynamic here -----------
-    last = jnp.where(
-        cnt > 0, bounds_ref[0, jnp.clip(cnt - 1, 0, mc - 1)], 0)
-    need = (t0 == last_t0) & (last < n_row) & (n_row > 0)
-    pe0 = prefix_at(0, carry0, n_row)  # past-payload bytes are zero padding,
-    pe1 = prefix_at(1, carry1, n_row)  # so the clipped read is exact even
-    fp0 = _mulmod31(_addmod(pe0, P31 - ps_0),  # when n_row is behind t0
-                    end_lookup(rend, 0, n_row))
-    fp1 = _mulmod31(_addmod(pe1, P31 - ps_1),
-                    end_lookup(rend, 1, n_row))
-    idx = jnp.minimum(cnt, mc - 1)
-    keep = need & (cnt < mc)
-    bounds_ref[0, idx] = jnp.where(keep, n_row, bounds_ref[0, idx])
-    lens_ref[0, idx] = jnp.where(keep, n_row - s, lens_ref[0, idx])
-    fps_ref[0, idx, 0] = jnp.where(keep, fp0, fps_ref[0, idx, 0])
-    fps_ref[0, idx, 1] = jnp.where(keep, fp1, fps_ref[0, idx, 1])
+    need = (t0 == last_t0) & (out.last_bound(cnt) < n_row) & (n_row > 0)
+
+    @pl.when(need)
+    def _fixup():
+        # past-payload bytes are zero padding, so the clipped read is exact
+        # even when n_row is behind t0
+        fps = [mulmod(addmod(prefix_at(g, n_row), P31 - ps),
+                      end_lookup(rend_ref, g, n_row), 31)
+               for g, ps in ((0, ps0), (1, ps1))]
+        out.put(cnt, n_row, n_row - s, *fps)
+
     cnt = cnt + need.astype(jnp.int32)
 
     # -- persist state for the next tile --------------------------------------
-    counts_ref[0, 0] = cnt
-    sti_ref[...] = jnp.stack([k, c, s, cnt, se])
-    sth_ref[0, 0] = _addmod(carry0, _mulmod31(rneg[0], tile_prefix(0, tile)))
-    sth_ref[0, 1] = _addmod(carry1, _mulmod31(rneg[1], tile_prefix(1, tile)))
-    sth_ref[1, 0] = ps_0
-    sth_ref[1, 1] = ps_1
-    # left stash for the next tile: P(next_t0 - q), q in [0, HL] (tile > HL,
-    # asserted by the wrapper, so every read lands inside this tile's limbs)
-    li = tile - 1 - jnp.arange(HL + 1, dtype=jnp.int32)
-    for g, carry_g in ((0, carry0), (1, carry1)):
-        parts = _addmod(_fold32(lo[g][li]), _rot31(_fold32(hi[g][li]), 16))
-        sps_ref[g] = _addmod(carry_g, _mulmod31(rneg[g], parts))
+    counts_ref[...] = jnp.full(counts_ref.shape, cnt, jnp.int32)
+    for i, v in enumerate((k, c, s, cnt, se)):
+        st_ref[i] = v
+    for g in range(2):
+        st_ref[5 + g] = addmod(
+            carry[g], mulmod(rneg[g], hl.total(g, tile // GROUP), 31))
+        st_ref[9 + g] = carry[g]
+        st_ref[11 + g] = rneg[g]
+    st_ref[7] = ps0
+    st_ref[8] = ps1
+    # this tile's hash lanes answer the next tile's behind-t0 max-size cuts
+    wprev_ref[...] = w_ref[...]
+
+    def copy_gp(i, _):
+        gp_ref[2 * (groups + 1) + i] = gp_ref[i]
+        return _
+
+    jax.lax.fori_loop(0, 2 * (groups + 1), copy_gp, 0)
 
 
 @functools.partial(
@@ -626,7 +628,7 @@ def packed_pipeline_batch(
     *,
     max_chunks: int,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Chunk + fingerprint a segment-packed ``(B, S)`` batch in one dispatch.
 
@@ -644,8 +646,8 @@ def packed_pipeline_batch(
     offset from the *chunk end*), so packed-row fps equal per-stream fps
     with no correction; only the prefix bookkeeping inside the kernel needs
     the per-segment ``P(end)``/``r^(end-1)`` operands, computed here from
-    the row bytes with the same 16-bit-limb trick the kernel uses (exact
-    because ``S <= 65536``, enforced below — one packed row is at most the
+    the row bytes with 16-bit-limb cumulative sums (exact because
+    ``S <= 65536``, enforced below — one packed row is at most the
     fingerprint kernel's own byte bound).
     """
     assert data.ndim == 2, data.shape
@@ -653,10 +655,7 @@ def packed_pipeline_batch(
     G = ends.shape[-1]
     mc = max_chunks
     if n == 0:  # static: no chunks
-        return (jnp.full((B, mc), _BIG, jnp.int32),
-                jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B, mc, 2), jnp.uint32),
-                jnp.zeros((B, mc), jnp.int32))
+        return _empty(B, mc)
     if p.max_size > MAX_CHUNK:
         raise ValueError(
             f"max_size {p.max_size} exceeds the fingerprint power-table "
@@ -667,56 +666,32 @@ def packed_pipeline_batch(
             f"packed row width {n} exceeds the limb-exactness bound "
             f"{MAX_CHUNK}; pack into narrower rows"
         )
-    L = p.seq_length
-    W = p.block_width
-    halo = p.skip_size + L - 1
-    nb_split = (n + p.skip_size + W + W - 1) // W
-    cover = nb_split * W
-    tile = min(tile, (cover + 1023) // 1024 * 1024)
-    assert tile % 1024 == 0 and tile % W == 0, (tile, W)
-    assert tile + halo <= MAX_CHUNK, (tile, halo)
-    nt = (cover + tile - 1) // tile
-    n_pad = nt * tile
-    # the left-prefix stash reaches skip_size positions into the previous
-    # tile; a skip wider than a tile would outrun it
+    tile, nt, hrows, nb_split = _geometry(n, p, tile)
+    # a max-size cut reaches skip_size positions into the previous tile,
+    # whose hash lanes are kept one tile longer
     assert p.skip_size < tile, (p.skip_size, tile)
-
-    x = jnp.pad(data.astype(jnp.uint8), ((0, 0), (0, n_pad - n)))
+    R = tile // LANES
+    ext_rows = R + hrows
+    x = lanes.as_rows(data.astype(jnp.uint8), nt * R + hrows)
     # padding positions carry seg end 0: every clipped mask bit is false
     # there (pos >= n > 0 >= sep - L), matching the zero-pad bytes
-    sep = jnp.pad(seg_end_pos.astype(jnp.int32), ((0, 0), (0, n_pad - n)))
-    xh = jnp.pad(x, ((0, 0), (0, halo)))
-    halos = jnp.stack(
-        [xh[:, (i + 1) * tile:(i + 1) * tile + halo] for i in range(nt)],
-        axis=1,
-    )
-    t0s = (jnp.arange(nt, dtype=jnp.int32) * tile).reshape(nt, 1)
-
-    pm = (1 << 31) - 1
-    wneg = jnp.stack(
-        [jnp.asarray(_negpow_table_np(r, tile + halo)) for r in (R1, R2)]
-    )
-    postab = jnp.stack(
-        [jnp.asarray(_pow_table_np(r)[: tile + halo]) for r in (R1, R2)]
-    )
-    rneg = jnp.asarray(np.array(
-        [[pow(pow(r, pm - 2, pm), i * tile, pm) for r in (R1, R2)]
-         for i in range(nt)], dtype=np.uint32))
-    rpos = jnp.asarray(np.array(
-        [[pow(r, i * tile, pm) for r in (R1, R2)] for i in range(nt)],
-        dtype=np.uint32))
+    sep = lanes.as_rows(seg_end_pos.astype(jnp.int32), nt * R)
+    wneg, postab = lanes.hash_tables(ext_rows)
+    tsc = jnp.asarray(lanes.tile_scalars(nt, tile))
 
     # per-segment end operands: pend[b, g, i] = P_g(end_i) and
     # rend[b, g, i] = r_g^(end_i - 1) — row-wide limb prefix sums gathered
     # at the segment ends (uint32 cumsums of < 2^16 limbs over n <= 65536
-    # entries: exact, the kernel's own argument)
+    # entries: exact)
+    from repro.dedup.fingerprint import _addmod, _byte_mulmod, _fold32, _rot31
+
     ends = ends.astype(jnp.int32)
     e_idx = jnp.clip(ends - 1, 0, n - 1)  # (B, G)
     full_pow = jnp.stack(
         [jnp.asarray(_pow_table_np(r)[:n]) for r in (R1, R2)]
     )  # (2, n): r^q for q < n; end - 1 < n always
     wneg_row = jnp.stack(
-        [jnp.asarray(_negpow_table_np(r, n)) for r in (R1, R2)]
+        [jnp.asarray(lanes.negpow_table(r, n)) for r in (R1, R2)]
     )
     pr, rr = [], []
     for g in range(2):
@@ -729,47 +704,38 @@ def packed_pipeline_batch(
         )
         pr.append(jnp.where(ends > 0, pg, jnp.uint32(0)))
         rr.append(jnp.where(ends > 0, full_pow[g][e_idx], jnp.uint32(0)))
-    pend = jnp.stack(pr, axis=1)  # (B, 2, G)
-    rend = jnp.stack(rr, axis=1)  # (B, 2, G)
+    gr = lanes.round_up(G, GROUP) // LANES
+    pend = lanes.as_rows(jnp.stack(pr, axis=1).astype(jnp.int32), gr)
+    rend = lanes.as_rows(jnp.stack(rr, axis=1).astype(jnp.int32), gr)
+    ends_rows = lanes.as_rows(ends, gr)  # zero padding: never an end > x
+    out_specs, out_shape = _out_specs_shapes(B, mc)
+    groups = ext_rows // 8
 
-    from jax.experimental.pallas import tpu as pltpu
-
-    bounds, counts, fps, lens = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(
-            _packed_pipeline_kernel, p=p, mc=mc, tile=tile, halo=halo,
+            _packed_pipeline_kernel, p=p, mc=mc, tile=tile, hrows=hrows,
             nb_split=nb_split, last_t0=(nt - 1) * tile,
         ),
         grid=(B, nt),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (i, 0)),  # t0
-            pl.BlockSpec((1, tile), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1, halo), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tile), lambda b, i: (b, i)),  # seg_end_pos
-            pl.BlockSpec((1, G), lambda b, i: (b, 0)),  # ends
-            pl.BlockSpec((1, 2, G), lambda b, i: (b, 0, 0)),  # P(end)
-            pl.BlockSpec((1, 2, G), lambda b, i: (b, 0, 0)),  # r^(end-1)
-            pl.BlockSpec((1, 2), lambda b, i: (i, 0)),  # r^-t0
-            pl.BlockSpec((1, 2), lambda b, i: (i, 0)),  # r^t0
-            pl.BlockSpec((2, tile + halo), lambda b, i: (0, 0)),
-            pl.BlockSpec((2, tile + halo), lambda b, i: (0, 0)),
+            pl.BlockSpec((None, 1, 8), lambda b, i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            *_byte_specs(R, hrows),
+            pl.BlockSpec((None, R, LANES), lambda b, i: (b, i, 0)),  # sep
+            pl.BlockSpec((None, gr, LANES), lambda b, i: (b, 0, 0)),  # ends
+            pl.BlockSpec((None, 2, gr, LANES), lambda b, i: (b, 0, 0, 0)),
+            pl.BlockSpec((None, 2, gr, LANES), lambda b, i: (b, 0, 0, 0)),
+            *_table_specs(ext_rows),
         ],
-        out_specs=[
-            pl.BlockSpec((1, mc), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, mc, 2), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, mc), lambda b, i: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, mc), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, mc, 2), jnp.uint32),
-            jax.ShapeDtypeStruct((B, mc), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((5,), jnp.int32),  # automaton k, c, s, cnt, se
-            pltpu.VMEM((2, 2), jnp.uint32),  # P(t0) carry, P(s) latch
-            pltpu.VMEM((2, p.skip_size + 1), jnp.uint32),  # P(t0-q) stash
+            pltpu.SMEM((13,), jnp.int32),  # scan + hash registers
+            pltpu.SMEM((4 * (groups + 1),), jnp.int32),  # group P, 2 tiles
+            pltpu.VMEM((2, ext_rows, LANES), jnp.int32),  # hash weights
+            pltpu.VMEM((2, ext_rows, LANES), jnp.int32),  # previous tile's
+            pltpu.VMEM((R, LANES), jnp.int32),  # packed mask lanes
         ],
         interpret=interpret,
-    )(t0s, x, halos, sep, ends, pend, rend, rneg, rpos, wneg, postab)
-    return bounds, counts[:, 0], fps, lens
+    )(tsc, x, x, sep, ends_rows, pend, rend, wneg, postab)
+    return _unpack_outputs(*outs, mc)

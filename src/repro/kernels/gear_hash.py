@@ -49,7 +49,7 @@ def gear_hash_pallas(
     table: jax.Array | None = None,
     *,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Per-position uint32 Gear hash of a 1-D uint8 stream (any length)."""
     assert data.ndim == 1, data.shape

@@ -1,13 +1,12 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` mode is selected automatically: on CPU (this container) the
-kernel bodies execute via the Pallas interpreter for bit-exact validation
-against ref.py; on TPU they compile to Mosaic.  Override with
-REPRO_PALLAS_INTERPRET=0/1.
+The backend decides how a kernel runs, and nothing overrides it: on the
+CPU the kernel bodies execute in the Pallas interpreter (bit-exact
+validation against ref.py in the tests); on a TPU they compile to Mosaic.
+Any other backend is refused rather than interpreted, so a chip run can
+never fall back to the interpreter unnoticed.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -17,10 +16,40 @@ from . import seqcdc_masks as _seqcdc_masks
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend, False on a TPU; raises on any other."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise NotImplementedError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU; "
+        f"backend {backend!r} is neither"
+    )
+
+
+#: why the baseline-only kernels are refused on a TPU: the chip compiler's
+#: own first refusal for each (docs/KERNELS.md, "compiles on v5e")
+_NOT_ON_TPU = {
+    "gear_hash": (
+        "the Gear kernel does not compile for the TPU: its (1, 31) halo "
+        "block is not (8, 128)-aligned, and its per-byte 256-entry table "
+        "gather has no Mosaic lowering"
+    ),
+    "block_max": (
+        "the block-max kernel does not compile for the TPU: Mosaic reduces "
+        "no unsigned integers, and its 1-D uint8 blocks do not match the "
+        "chip's (1024)(128)(4,1) byte layout"
+    ),
+}
+
+
+def _interpret_or_refuse(kernel: str) -> bool:
+    """Interpret on the CPU; refuse a kernel that has no chip build."""
+    interpret = _interpret()
+    if not interpret:
+        raise NotImplementedError(_NOT_ON_TPU[kernel])
+    return interpret
 
 
 def seqcdc_masks(data, seq_length: int, mode: str = "increasing"):
@@ -32,12 +61,14 @@ def seqcdc_masks(data, seq_length: int, mode: str = "increasing"):
 
 def gear_hash(data, table=None):
     """Per-position uint32 Gear hash via the parallel window-32 kernel."""
-    return _gear_hash.gear_hash_pallas(data, table, interpret=_interpret())
+    return _gear_hash.gear_hash_pallas(
+        data, table, interpret=_interpret_or_refuse("gear_hash"))
 
 
 def block_max(data, block: int = 128):
     """Per-block byte maxima via the range-scan kernel."""
-    return _extremum.block_max_pallas(data, block=block, interpret=_interpret())
+    return _extremum.block_max_pallas(
+        data, block=block, interpret=_interpret_or_refuse("block_max"))
 
 
 def flash_attention(q, k, v, **kw):

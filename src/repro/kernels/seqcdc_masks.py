@@ -2,17 +2,21 @@
 
 TPU adaptation of the paper's AVX-512 scan (SSIII-D, Fig. 3).  The AVX version
 loads 64-byte registers at offsets 0..SeqLength-1 and combines pairwise
-``cmpgt`` masks; here each grid step stages a TILE-byte VMEM block (plus an
-(L-1)-byte halo from the next tile, passed as a second operand so BlockSpecs
-stay non-overlapping) and performs the same shifted compares on 8x128 VPU
-lanes.  Per byte of input the kernel does L-1 compares + L-2 ANDs + 1 compare
+``cmpgt`` masks; here each grid step stages a TILE-byte VMEM block (plus a
+halo of the next tile's first bytes, a second block view of the same array
+so BlockSpecs stay non-overlapping) and performs the same shifted compares
+on 8x128 VPU lanes.  Per byte of input the kernel does L-1 compares + L-2 ANDs + 1 compare
 — arithmetic intensity ~L ops/byte, firmly HBM-bandwidth-bound, which is the
 design point: phase 1 runs at memory speed and phase 2 (core/automaton.py)
 touches only per-block summaries.
 
-VMEM budget per grid step (TILE = 64 KiB): input 64 KiB + halo + 2x64 KiB
-bool outputs + shifted temporaries ~ 0.4 MiB << 16 MiB VMEM.  TILE is a
-multiple of 1024 so the flattened byte vector maps onto whole (8,128) tiles.
+Layout (``kernels/lanes.py``): a TILE-byte block is a ``(TILE // 128,
+128)`` uint8 array and the halo is the first ``(32, 128)`` block of the
+next tile, so every block is tile-aligned for the chip compiler; the
+shifted compares are lane rolls.  Outputs are int8 0/1 lanes (Mosaic
+stores no bool arrays) turned into bool bitmaps by the wrapper.  VMEM per
+grid step (TILE = 64 KiB): input 64 KiB + 4 KiB halo + 2 x 64 KiB outputs
++ int32 shifted temporaries ~ 1.5 MiB << 16 MiB VMEM.
 """
 from __future__ import annotations
 
@@ -22,25 +26,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import lanes
+
 DEFAULT_TILE = 64 * 1024
 
 
-def _masks_kernel(x_ref, tail_ref, cand_ref, opp_ref, *, L: int, inc: bool):
-    x = x_ref[...]  # (TILE,) uint8
-    t = tail_ref[0]  # (HALO,) uint8 : first HALO bytes of the next tile
-    ext = jnp.concatenate([x, t])  # (TILE + L - 1,)
-    a = ext[:-1]
-    b = ext[1:]
-    gt = b > a  # (TILE + L - 2,)
-    lt = b < a
-    fwd = gt if inc else lt
-    opp = lt if inc else gt
-    tile = x.shape[0]
-    acc = fwd[:tile]
-    for j in range(1, L - 1):  # AND of L-1 shifted pair masks (paper's M1&M2&..)
-        acc = jnp.logical_and(acc, fwd[j : j + tile])
-    cand_ref[...] = acc
-    opp_ref[...] = opp[:tile]
+def _masks_kernel(x_ref, halo_ref, cand_ref, opp_ref, *, L: int, inc: bool):
+    ext = jnp.concatenate([x_ref[...].astype(jnp.int32),
+                           halo_ref[...].astype(jnp.int32)], axis=0)
+    cand, opp = lanes.mask_lanes(ext, x_ref.shape[0], L, inc)
+    cand_ref[...] = cand.astype(jnp.int8)
+    opp_ref[...] = opp.astype(jnp.int8)
 
 
 @functools.partial(
@@ -52,7 +48,7 @@ def seqcdc_masks_pallas(
     mode: str = "increasing",
     *,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(candidate, opposing) bitmaps for a 1-D uint8 stream of any length.
 
@@ -63,37 +59,41 @@ def seqcdc_masks_pallas(
     assert data.ndim == 1, data.shape
     n = data.shape[0]
     L = int(seq_length)
-    halo = max(L - 1, 1)
+    if L > lanes.LANES:
+        raise ValueError(f"seq_length {L} exceeds {lanes.LANES}")
     inc = mode == "increasing"
     if n == 0:
         z = jnp.zeros((0,), dtype=bool)
         return z, z
-    tile = min(tile, max(1024, ((n + 1023) // 1024) * 1024))
-    n_pad = (n + tile - 1) // tile * tile
-    x = jnp.pad(data.astype(jnp.uint8), (0, n_pad - n))
-    nt = n_pad // tile
-    # tails[i] = x[(i+1)*tile : (i+1)*tile + halo], zero past the end
-    tails = jnp.pad(x, (0, tile)).reshape(nt + 1, tile)[1:, :halo]
+    hrows = lanes.halo_rows(L - 1)
+    quantum = hrows * lanes.LANES
+    tile = max(quantum, min(lanes.round_up(tile, quantum),
+                            lanes.round_up(n, quantum)))
+    nt = (n + tile - 1) // tile
+    R = tile // lanes.LANES
+    x = lanes.as_rows(data.astype(jnp.uint8), nt * R + hrows)
 
     cand, opp = pl.pallas_call(
         functools.partial(_masks_kernel, L=L, inc=inc),
         grid=(nt,),
         in_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((1, halo), lambda i: (i, 0)),
+            pl.BlockSpec((R, lanes.LANES), lambda i: (i, 0)),
+            # the halo: the next tile's first block of the same array
+            pl.BlockSpec((hrows, lanes.LANES),
+                         lambda i: ((i + 1) * (R // hrows), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((R, lanes.LANES), lambda i: (i, 0)),
+            pl.BlockSpec((R, lanes.LANES), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
-            jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
+            jax.ShapeDtypeStruct((nt * R, lanes.LANES), jnp.int8),
+            jax.ShapeDtypeStruct((nt * R, lanes.LANES), jnp.int8),
         ],
         interpret=interpret,
-    )(x, tails)
+    )(x, x)
 
     idx = jnp.arange(n)
-    cand = jnp.where(idx <= n - L, cand[:n], False)
-    opp = jnp.where(idx < n - 1, opp[:n], False)
+    cand = (idx <= n - L) & (lanes.unrows(cand, n) != 0)
+    opp = (idx < n - 1) & (lanes.unrows(opp, n) != 0)
     return cand, opp

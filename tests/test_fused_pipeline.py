@@ -101,7 +101,8 @@ def test_fused_pipeline_empty_and_single_byte(rng):
 def test_fused_pipeline_single_stream_wrapper(rng):
     d = rng.integers(0, 256, 5000, dtype=np.uint8)
     mc = max_chunks_for(d.size, P)
-    b1, c1, f1, l1 = fused_pipeline(jnp.asarray(d), P, max_chunks=mc)
+    b1, c1, f1, l1 = fused_pipeline(jnp.asarray(d), P, max_chunks=mc,
+                                    interpret=True)
     b2, c2, f2, l2 = fused_pipeline_batch(jnp.asarray(d)[None], P,
                                           max_chunks=mc, interpret=True)
     np.testing.assert_array_equal(np.asarray(b1), np.asarray(b2)[0])
