@@ -1,0 +1,10 @@
+"""Device, in first full backups, every chunk new: share of the traced
+window in which no operation ran on the device (1 - union of the operation
+intervals / window)."""
+
+
+def read(rec):
+    t = rec["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
