@@ -21,7 +21,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
-from . import automaton, masks
+from . import automaton, masks, stages
 from .params import SeqCDCParams
 
 _BIG = jnp.int32(1 << 30)
@@ -56,10 +56,12 @@ def boundaries_two_phase(
     if n == 0:  # static: an empty stream has no chunks
         mc = max_chunks or automaton.max_chunks_for(n, p)
         return jnp.full((mc,), _BIG, dtype=jnp.int32), jnp.int32(0)
-    cand, opp = _compute_masks(data, p, mask_impl)
-    return automaton.select_boundaries(
-        cand, opp, n, p, step_impl=step_impl, max_chunks=max_chunks
-    )
+    with jax.named_scope(stages.MASKS):
+        cand, opp = _compute_masks(data, p, mask_impl)
+    with jax.named_scope(stages.AUTOMATON):
+        return automaton.select_boundaries(
+            cand, opp, n, p, step_impl=step_impl, max_chunks=max_chunks
+        )
 
 
 @functools.partial(
@@ -96,13 +98,15 @@ def boundaries_packed(
     S = data.shape[-1]
     if S == 0:  # static: an empty row has no chunks
         return jnp.full((max_chunks,), _BIG, dtype=jnp.int32), jnp.int32(0)
-    cand, opp = _compute_masks(data, p, mask_impl)
-    pos = jnp.arange(S, dtype=jnp.int32)
-    cand = cand & (pos <= seg_end_pos - p.seq_length)
-    opp = opp & (pos < seg_end_pos - 1)
-    return automaton.select_boundaries_packed(
-        cand, opp, ends, p, max_chunks=max_chunks
-    )
+    with jax.named_scope(stages.MASKS):
+        cand, opp = _compute_masks(data, p, mask_impl)
+        pos = jnp.arange(S, dtype=jnp.int32)
+        cand = cand & (pos <= seg_end_pos - p.seq_length)
+        opp = opp & (pos < seg_end_pos - 1)
+    with jax.named_scope(stages.AUTOMATON):
+        return automaton.select_boundaries_packed(
+            cand, opp, ends, p, max_chunks=max_chunks
+        )
 
 
 def boundaries_packed_batch(

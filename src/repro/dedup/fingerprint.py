@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import stages
+
 #: backend for :func:`chunk_fingerprints`: the jnp ``searchsorted``/gather/
 #: ``segment_sum`` chain ("reference") or the fused Pallas kernel
 #: (kernels/fingerprint.py) — bit-identical, guarded by the scheduler's
@@ -119,35 +121,39 @@ def chunk_fingerprints(
     (kernels/fingerprint.py, interpreted on the CPU, compiled on a TPU) —
     bit-identical output, no per-byte gather/scatter.
     """
-    if fp_impl == "pallas":
-        from repro.kernels import ops  # lazy: no cycle (see ops docstring)
+    with jax.named_scope(stages.FINGERPRINT):
+        if fp_impl == "pallas":
+            # lazy: no cycle (see the ops docstring)
+            from repro.kernels import ops
 
-        return ops.chunk_fingerprints(data, bounds, count,
-                                      max_chunks=max_chunks)
-    if fp_impl != "reference":
-        raise ValueError(f"unknown fp_impl {fp_impl!r}")
-    n = data.shape[-1]
-    d = data.astype(jnp.uint32)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    # chunk id per byte: first j with bounds[j] > idx  (sentinel keeps it valid)
-    seg = jnp.searchsorted(bounds, idx, side="right").astype(jnp.int32)
-    seg = jnp.minimum(seg, max_chunks - 1)
-    end = bounds[seg]
-    e = jnp.clip(end - 1 - idx, 0, MAX_CHUNK - 1)  # offset from chunk end
+            return ops.chunk_fingerprints(data, bounds, count,
+                                          max_chunks=max_chunks)
+        if fp_impl != "reference":
+            raise ValueError(f"unknown fp_impl {fp_impl!r}")
+        n = data.shape[-1]
+        d = data.astype(jnp.uint32)
+        idx = jnp.arange(n, dtype=jnp.int32)
+        # chunk id per byte: first j with bounds[j] > idx (the sentinel
+        # keeps it valid)
+        seg = jnp.searchsorted(bounds, idx, side="right").astype(jnp.int32)
+        seg = jnp.minimum(seg, max_chunks - 1)
+        end = bounds[seg]
+        e = jnp.clip(end - 1 - idx, 0, MAX_CHUNK - 1)  # offset from the end
 
-    fps = []
-    for r in (R1, R2):
-        pow_r = jnp.asarray(_pow_table_np(r))
-        contrib = _byte_mulmod(d, pow_r[e])
-        fps.append(_segment_fold(contrib, seg, max_chunks))
-    fp = jnp.stack(fps, axis=-1)
+        fps = []
+        for r in (R1, R2):
+            pow_r = jnp.asarray(_pow_table_np(r))
+            contrib = _byte_mulmod(d, pow_r[e])
+            fps.append(_segment_fold(contrib, seg, max_chunks))
+        fp = jnp.stack(fps, axis=-1)
 
-    starts = jnp.concatenate([jnp.zeros((1,), bounds.dtype), bounds[:-1]])
-    lengths = (bounds - starts).astype(jnp.int32)
-    valid = jnp.arange(max_chunks) < count
-    fp = jnp.where(valid[:, None], fp, 0)
-    lengths = jnp.where(valid, lengths, 0)
-    return fp, lengths
+        starts = jnp.concatenate([jnp.zeros((1,), bounds.dtype),
+                                  bounds[:-1]])
+        lengths = (bounds - starts).astype(jnp.int32)
+        valid = jnp.arange(max_chunks) < count
+        fp = jnp.where(valid[:, None], fp, 0)
+        lengths = jnp.where(valid, lengths, 0)
+        return fp, lengths
 
 
 def fingerprints_numpy(data: np.ndarray, bounds: np.ndarray) -> np.ndarray:

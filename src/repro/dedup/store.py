@@ -41,9 +41,10 @@ thread compressed them once, they travelled compressed over the RPC, and
 the store files the payload as-is under the client-computed key.
 
 Observability: :meth:`attach_obs` points the store at the owning service's
-``MetricsRegistry``; encode time lands in ``store.compress_s`` and
-compressed payload bytes in ``store.compressed_bytes{shard=}``
-(docs/OBSERVABILITY.md).
+``MetricsRegistry``; encode time lands in ``store.compress_s``,
+compressed payload bytes in ``store.compressed_bytes{shard=}``, and the
+seconds of each :meth:`put_stream`'s SHA-256 keys and block-file writes in
+``store.key_hash_s`` and ``store.block_write_s`` (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -181,11 +182,16 @@ class BlockStore:
         #: owning service's MetricsRegistry (attach_obs); None = uncounted
         self.obs = None
         self.obs_shard = 0
+        #: seconds spent writing block files, ever (file-backed stores)
+        self.write_s = 0.0
 
     def attach_obs(self, registry, shard: int = 0):
-        """Report compression telemetry into ``registry`` (labeled by
-        ``shard``): ``store.compress_s`` encode latency and
-        ``store.compressed_bytes{shard=}`` payload bytes written."""
+        """Report telemetry into ``registry``: compression
+        (``store.compress_s`` encode latency and
+        ``store.compressed_bytes{shard=}`` payload bytes written, labeled by
+        ``shard``) and, once per :meth:`put_stream`, the seconds of its
+        SHA-256 keys (``store.key_hash_s``) and block-file writes
+        (``store.block_write_s``)."""
         self.obs = registry
         self.obs_shard = int(shard)
 
@@ -249,7 +255,10 @@ class BlockStore:
 
     def put(self, chunk: bytes) -> str:
         chunk = bytes(chunk)
-        key = sha256_key(chunk)
+        return self._put_keyed(sha256_key(chunk), chunk)
+
+    def _put_keyed(self, key: str, chunk: bytes) -> str:
+        """Store ``chunk`` under its SHA-256 ``key`` (one reference)."""
         self.logical_bytes += len(chunk)
         if key not in self.refs:
             csize = self._write_block(key, chunk)
@@ -330,10 +339,19 @@ class BlockStore:
                 "(last bound must equal len(data))"
             )
         keys = []
+        hash_s = 0.0
+        write0 = self.write_s
         s = 0
         for e in ends:
-            keys.append(self.put(data[s:e].tobytes()))
+            chunk = data[s:e].tobytes()
+            t0 = time.perf_counter()
+            key = sha256_key(chunk)
+            hash_s += time.perf_counter() - t0
+            keys.append(self._put_keyed(key, chunk))
             s = e
+        if self.obs is not None:
+            self.obs.inc("store.key_hash_s", hash_s)
+            self.obs.inc("store.block_write_s", self.write_s - write0)
         return keys
 
     # -- get --------------------------------------------------------------------
@@ -638,10 +656,12 @@ class DirBlockStore(BlockStore):
         return raw, codec, len(payload)
 
     def _atomic_write(self, path: str, payload: bytes):
+        t0 = time.perf_counter()
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
             f.write(payload)
         os.replace(tmp, path)
+        self.write_s += time.perf_counter() - t0
 
     # -- put / tiering -----------------------------------------------------------
     def _write_block(self, key: str, raw: bytes) -> int:
@@ -714,19 +734,17 @@ class DirBlockStore(BlockStore):
             self.obs.inc(labeled("store.tier_demotions",
                                  shard=self.obs_shard))
 
-    def put(self, chunk: bytes) -> str:
+    def _put_keyed(self, key: str, chunk: bytes) -> str:
         # the refcount fast path must still consult *file presence*: a
         # stale manifest (crash between a delete's unlink and its manifest
         # sync) may list a key whose file is gone, and a committed recipe
         # must never name bytes that are not on disk — re-puts of such a
         # key rewrite the file
-        chunk = bytes(chunk)
-        key = sha256_key(chunk)
         if key in self.refs and self._find_block(key)[0] is None:
             old = self.csizes.get(key, self.sizes.get(key, 0))
             csize = self._write_block(key, chunk)
             self.compressed_bytes += csize - old
-        return super().put(chunk)
+        return super()._put_keyed(key, chunk)
 
     def put_compressed_blocks(self, keys: Sequence[str],
                               raw_sizes: Sequence[int], codec,

@@ -151,6 +151,7 @@ def fingerprint_pallas(
             pltpu.VMEM((2, R, LANES), jnp.int32),  # hash weights
         ],
         interpret=interpret,
+        name="chunk_fingerprint",
     )(tsc, x, lanes.as_rows(b32, mcr), wneg, postab)
 
     fp = lanes.unrows(fps, max_chunks).T.astype(jnp.uint32)

@@ -382,6 +382,7 @@ def fused_pipeline_batch(
             pltpu.VMEM((R, LANES), jnp.int32),  # packed mask lanes
         ],
         interpret=interpret,
+        name="chunk_fused",
     )(tsc, x, x, wneg, postab)
     return _unpack_outputs(*outs, mc)
 
@@ -737,5 +738,6 @@ def packed_pipeline_batch(
             pltpu.VMEM((R, LANES), jnp.int32),  # packed mask lanes
         ],
         interpret=interpret,
+        name="chunk_fused",
     )(tsc, x, x, sep, ends_rows, pend, rend, wneg, postab)
     return _unpack_outputs(*outs, mc)

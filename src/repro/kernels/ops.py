@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.core import stages
+
 from . import extremum as _extremum
 from . import gear_hash as _gear_hash
 from . import seqcdc_masks as _seqcdc_masks
@@ -103,13 +105,14 @@ def fused_pipeline(data, p, *, max_chunks: int):
     """
     from . import fused_pipeline as _fpipe
 
-    if data.ndim == 1:
-        return _fpipe.fused_pipeline(
+    with jax.named_scope(stages.FUSED):
+        if data.ndim == 1:
+            return _fpipe.fused_pipeline(
+                data, p, max_chunks=max_chunks, interpret=_interpret()
+            )
+        return _fpipe.fused_pipeline_batch(
             data, p, max_chunks=max_chunks, interpret=_interpret()
         )
-    return _fpipe.fused_pipeline_batch(
-        data, p, max_chunks=max_chunks, interpret=_interpret()
-    )
 
 
 def packed_pipeline(data, seg_end_pos, ends, p, *, max_chunks: int):
@@ -125,7 +128,8 @@ def packed_pipeline(data, seg_end_pos, ends, p, *, max_chunks: int):
     """
     from . import fused_pipeline as _fpipe
 
-    return _fpipe.packed_pipeline_batch(
-        data, seg_end_pos, ends, p, max_chunks=max_chunks,
-        interpret=_interpret(),
-    )
+    with jax.named_scope(stages.FUSED):
+        return _fpipe.packed_pipeline_batch(
+            data, seg_end_pos, ends, p, max_chunks=max_chunks,
+            interpret=_interpret(),
+        )

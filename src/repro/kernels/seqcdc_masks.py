@@ -91,6 +91,7 @@ def seqcdc_masks_pallas(
             jax.ShapeDtypeStruct((nt * R, lanes.LANES), jnp.int8),
         ],
         interpret=interpret,
+        name="chunk_masks",
     )(x, x)
 
     idx = jnp.arange(n)

@@ -9,9 +9,12 @@ The measurement substrate every service layer reports through
   ``ShardedDedupService.metrics()``) into one.
 * :func:`span` — causal tracing context manager emitting JSONL records
   (trace/span/parent IDs + wall/CPU time + byte counts) when
-  ``REPRO_TRACE`` is set; a shared no-op otherwise.  :func:`current_context`
+  ``REPRO_TRACE`` is set; otherwise only its profiler annotation (below),
+  or a shared no-op.  :func:`current_context`
   and :func:`scope` carry the causal chain across thread and process seams
-  (writer queue, shard RPC).
+  (writer queue, shard RPC).  :func:`set_annotator` puts every span and
+  request phase on the JAX profiler's timeline too, as ``repro.<name>``
+  (installed by the layer that imports jax).
 * :class:`PhaseClock` — exact wall-time partitioner behind the
   ``req.latency_s{op=,phase=}`` request histograms: phases tile the
   request's wall time by construction, so per-phase sums reconcile with
@@ -30,7 +33,15 @@ from .metrics import (
     labeled,
     merge_snapshots,
 )
-from .trace import TRACE_ENV, Span, current_context, enabled, scope, span
+from .trace import (
+    TRACE_ENV,
+    Span,
+    current_context,
+    enabled,
+    scope,
+    set_annotator,
+    span,
+)
 
 __all__ = [
     "BUCKETS_PER_OCTAVE",
@@ -45,5 +56,6 @@ __all__ = [
     "labeled",
     "merge_snapshots",
     "scope",
+    "set_annotator",
     "span",
 ]

@@ -44,6 +44,8 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from .trace import annotate
+
 #: geometric histogram resolution: 4 buckets per factor of two
 BUCKETS_PER_OCTAVE = 4
 
@@ -264,20 +266,24 @@ class MetricsRegistry:
 
 
 class _Phase:
-    """Context manager arm of :meth:`PhaseClock.phase`."""
+    """Context manager arm of :meth:`PhaseClock.phase`; the phase is also
+    the profiler annotation ``repro.phase.<name>`` (:func:`annotate`)."""
 
-    __slots__ = ("_clock", "_name")
+    __slots__ = ("_clock", "_name", "_ann")
 
     def __init__(self, clock: "PhaseClock", name: str):
         self._clock = clock
         self._name = name
+        self._ann = annotate("phase." + name)
 
     def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
         self._clock._push(self._name)
         return self
 
     def __exit__(self, *exc):
         self._clock._pop()
+        self._ann.__exit__(*exc)
         return False
 
 

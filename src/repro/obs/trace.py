@@ -31,10 +31,12 @@ One JSON object per line (the v2 schema in docs/OBSERVABILITY.md):
      "cpu_s": ..., "pid": ..., "thread": "...", ...attrs}
 
 ``REPRO_TRACE`` selects the sink: a path appends JSONL there (parents
-created); ``1``/``stderr`` writes to stderr.  Unset (the default) makes
-:func:`span` return a shared no-op whose enter/exit is two attribute
-lookups — tracing must cost nothing when it is off, and must never change
-results when it is on (CI runs the whole tier-1 suite with it enabled).
+created); ``1``/``stderr`` writes to stderr.  Unset (the default) writes
+nothing: :func:`span` returns the profiler annotation alone (below), or,
+where no annotator is installed, a shared no-op whose enter/exit is two
+attribute lookups — tracing must cost next to nothing when it is off, and
+must never change results when it is on (CI runs the whole tier-1 suite
+with it enabled).
 
 The environment variable is re-read on every span start, so tests and
 long-lived services can toggle tracing without restarting; the output file
@@ -43,6 +45,16 @@ handle is cached per path and writes are serialized under one lock
 flushed line-by-line and the cached handle is closed at interpreter exit
 (``atexit``), so a shard server stopped via ``shutdown`` never truncates
 its tail spans.
+
+The profiler timeline: a layer that imports jax installs an *annotator*
+(:func:`set_annotator`, ``jax.profiler.TraceAnnotation`` in
+``repro.service.scheduler``), and from then on every span also enters an
+annotation named ``repro.<name>``, with ``REPRO_TRACE`` set or not, so a
+JAX profiler trace shows the program's spans on the device's clock.  An
+annotation records nothing while no profiler session runs; with
+``REPRO_TRACE`` unset, :func:`span` then costs one inactive annotation.
+:func:`annotate` gives the bare annotation to spans the JSONL sink does not
+carry (the request phases).
 
 Stdlib-only, like the rest of ``repro.obs`` — shard servers trace too.
 """
@@ -54,7 +66,7 @@ import os
 import sys
 import threading
 import time
-from typing import Optional, TextIO, Tuple
+from typing import Callable, ContextManager, Optional, TextIO, Tuple
 
 #: the switch: unset/empty = off; "1"/"stderr" = stderr; else = JSONL path
 TRACE_ENV = "REPRO_TRACE"
@@ -65,6 +77,20 @@ _sink_file: Optional[TextIO] = None
 
 #: per-thread context stack of (trace_id, span_id) — the causal chain
 _tls = threading.local()
+
+#: prefix of every annotation a span enters on the profiler timeline
+ANNOTATION_PREFIX = "repro."
+
+#: name -> context manager on the profiler's timeline; None = no profiler
+_annotator: Optional[Callable[[str], ContextManager]] = None
+
+
+def set_annotator(fn: Optional[Callable[[str], ContextManager]]):
+    """Install the profiler annotation every span also enters (``None``
+    removes it).  Called by the layer that imports jax, so this package
+    stays stdlib-only."""
+    global _annotator
+    _annotator = fn
 
 
 def enabled() -> bool:
@@ -204,16 +230,48 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+class _Annotated:
+    """A span that is only its profiler annotation (``REPRO_TRACE`` unset):
+    attributes set on it are dropped, as on the no-op span."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann: ContextManager):
+        self._ann = ann
+
+    def __enter__(self) -> "_Annotated":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+    def __setitem__(self, key, value):
+        pass
+
+
+def annotate(name: str):
+    """The profiler annotation ``repro.<name>`` alone, or the no-op when no
+    annotator is installed; it supports ``sp[key] = value`` like a span."""
+    ann = _annotator
+    if ann is None:
+        return _NULL
+    return _Annotated(ann(ANNOTATION_PREFIX + name))
+
+
 class Span:
     """One traced unit of work (use via :func:`span`, not directly)."""
 
-    __slots__ = ("name", "attrs", "_t0", "_c0", "_ids")
+    __slots__ = ("name", "attrs", "_t0", "_c0", "_ids", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self) -> "Span":
+        self._ann = annotate(self.name)
+        self._ann.__enter__()
         st = _stack()
         if st:
             trace_id, parent_id = st[-1]
@@ -231,6 +289,7 @@ class Span:
     def __exit__(self, etype, exc, tb) -> bool:
         wall = time.perf_counter() - self._t0
         cpu = time.thread_time() - self._c0
+        self._ann.__exit__(etype, exc, tb)
         st = _stack()
         if st:  # pop our own frame (LIFO: spans nest on one thread)
             st.pop()
@@ -260,9 +319,10 @@ class Span:
 def span(name: str, **attrs):
     """Start a span named ``name`` with initial attributes ``attrs``.
 
-    Returns the shared no-op when tracing is off, so call sites need no
-    ``if`` of their own.
+    With tracing off it returns the profiler annotation alone
+    (:func:`annotate`), or the shared no-op when no annotator is installed,
+    so call sites need no ``if`` of their own.
     """
     if not os.environ.get(TRACE_ENV):
-        return _NULL
+        return annotate(name)
     return Span(name, attrs)

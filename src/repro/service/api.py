@@ -395,7 +395,6 @@ class DedupService(ServiceBase):
         # device-side error — the submitted names are no longer pending, so
         # they must stop blocking resubmission
         with self._request("flush"):
-            t0 = time.perf_counter()
             with span("service.flush") as sp:
                 tail0 = self.scheduler.stats.tail_s
                 with self._phase("chunk-dispatch"):
@@ -422,7 +421,6 @@ class DedupService(ServiceBase):
                     with self._phase("sync"):
                         self.sync()
                 sp["objects"] = len(out)
-            self.obs.observe("service.flush_s", time.perf_counter() - t0)
             return out
 
     def _commit(self, res: ChunkResult) -> tuple[ObjectStat, List[str]]:
@@ -433,29 +431,37 @@ class DedupService(ServiceBase):
         """
         name = str(res.tag)
         old = self.recipes.get(name) if name in self.recipes else None
-        before = self.store.unique_chunks
-        keys = self.store.put_stream(res.data, res.bounds.tolist())
-        # a dedup hit = a chunk whose key the store already held; measured
-        # by the unique-count delta so no second hash pass runs
-        self.obs.inc("ingest.objects")
-        self.obs.inc("ingest.bytes", res.size)
-        self.obs.inc("ingest.chunks", len(keys))
-        self.obs.inc("ingest.dedup_hit_chunks",
-                     len(keys) - (self.store.unique_chunks - before))
-        recipe = ObjectRecipe(
-            name=name,
-            size=res.size,
-            sha256=hashlib.sha256(res.data).hexdigest(),
-            keys=keys,
-            chunk_lens=res.lengths.astype(int).tolist(),
-            # recorded when the scheduler fingerprinted (reshardability);
-            # with_fingerprints=False leaves the field absent
-            fps=pack_fps(res.fps) if res.fps.shape[0] == len(keys) else None,
-        )
-        if res.fps.size:
-            with self._phase("fp"):
-                self.fp_index.add_batch(res.fps, res.lengths)
-        self.recipes.add(recipe)
+        with span("commit.object", bytes=res.size) as sp:
+            before = self.store.unique_chunks
+            keys = self.store.put_stream(res.data, res.bounds.tolist())
+            # a dedup hit = a chunk whose key the store already held;
+            # measured by the unique-count delta so no second hash pass runs
+            new_chunks = self.store.unique_chunks - before
+            sp["chunks"] = len(keys)
+            sp["new_chunks"] = new_chunks
+            self.obs.inc("ingest.objects")
+            self.obs.inc("ingest.bytes", res.size)
+            self.obs.inc("ingest.chunks", len(keys))
+            self.obs.inc("ingest.dedup_hit_chunks", len(keys) - new_chunks)
+            t0 = time.perf_counter()
+            digest = hashlib.sha256(res.data).hexdigest()
+            self.obs.inc("commit.digest_s", time.perf_counter() - t0)
+            recipe = ObjectRecipe(
+                name=name,
+                size=res.size,
+                sha256=digest,
+                keys=keys,
+                chunk_lens=res.lengths.astype(int).tolist(),
+                # recorded when the scheduler fingerprinted
+                # (reshardability); with_fingerprints=False leaves the
+                # field absent
+                fps=(pack_fps(res.fps) if res.fps.shape[0] == len(keys)
+                     else None),
+            )
+            if res.fps.size:
+                with self._phase("fp"):
+                    self.fp_index.add_batch(res.fps, res.lengths)
+            self.recipes.add(recipe)
         return ObjectStat.of(recipe), (old.keys if old is not None else [])
 
     # -- serve ------------------------------------------------------------------
@@ -469,7 +475,6 @@ class DedupService(ServiceBase):
         """
         r = self.recipes.get(name)
         with self._request("get", object=name):
-            t0 = time.perf_counter()
             with span("service.get", object=name, bytes=r.size):
                 # "rpc" = the block-gather seam; for this single-store
                 # service it is the same seam served in-process
@@ -484,7 +489,6 @@ class DedupService(ServiceBase):
                         ) from e
                 with self._phase("verify"):
                     data = verify_restore(r, data)
-            self.obs.observe("service.get_s", time.perf_counter() - t0)
             self.obs.inc("restore.objects")
             self.obs.inc("restore.bytes", r.size)
             return data
@@ -529,8 +533,10 @@ class DedupService(ServiceBase):
 
     def sync(self):
         """Persist recipes + store manifest (no-op for in-memory backends)."""
-        self.recipes.sync()
-        self.store.sync()
+        with span("sync.recipes"):
+            self.recipes.sync()
+        with span("sync.manifest"):
+            self.store.sync()
 
     # -- accounting -------------------------------------------------------------
     def stats(self) -> ServiceStats:

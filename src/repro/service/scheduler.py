@@ -67,7 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import oracle
-from repro.obs import MetricsRegistry, labeled, span
+from repro.obs import MetricsRegistry, labeled, set_annotator, span
 from repro.core.automaton import max_chunks_for
 from repro.core.params import SeqCDCParams
 from repro.core.seqcdc import MaskImpl, StepImpl, boundaries_batch
@@ -77,6 +77,11 @@ from repro.dedup.fingerprint import (
     chunk_fingerprints,
     fingerprints_numpy,
 )
+
+# every span and request phase also lands on the JAX profiler's timeline
+# (as ``repro.<name>``); an annotation records nothing while no profiler
+# session runs
+set_annotator(jax.profiler.TraceAnnotation)
 
 #: mirrors kernels/fused_pipeline.py's PipelineImpl — declared locally so
 #: importing the service does not pull the Pallas toolchain in eagerly
@@ -526,9 +531,10 @@ class ChunkScheduler:
         rows = len(reqs)
         self._pending[bucket] = []
         payload = sum(r.data.size for r in reqs)
-        batch = np.zeros((rows, bucket), dtype=np.uint8)
-        for row, r in enumerate(reqs):
-            batch[row, : r.data.size] = r.data
+        with span("sched.pack", bucket=bucket, rows=rows):
+            batch = np.zeros((rows, bucket), dtype=np.uint8)
+            for row, r in enumerate(reqs):
+                batch[row, : r.data.size] = r.data
         with span("sched.dispatch", bucket=bucket, rows=len(reqs),
                   payload_bytes=payload, device_bytes=batch.size):
             t0 = time.perf_counter()
@@ -565,9 +571,6 @@ class ChunkScheduler:
         self.obs.inc("sched.dispatches")
         self.obs.inc("sched.device_bytes", batch.size)
         self.obs.inc("sched.payload_bytes", payload)
-        # partial batches no longer ship zero rows, so padded_rows stays 0;
-        # register the counter anyway so BENCH series keep the key
-        self.obs.inc("sched.padded_rows", 0)
         self.obs.observe(self._dispatch_hist, dispatch_s)
         occ_name, waste_name, rows_name = self._bucket_names(bucket)
         occ = payload / batch.size if batch.size else 0.0
@@ -609,25 +612,26 @@ class ChunkScheduler:
         G = 4  # segment-table width rounded to a power of two: the jit
         while G < max(len(rr) for rr in rows):  # cache stays logarithmic
             G <<= 1  # in the per-row stream count
-        batch = np.zeros((R, S), dtype=np.uint8)
-        sep = np.zeros((R, S), dtype=np.int32)
-        ends = np.zeros((R, G), dtype=np.int32)
         layout: List[List[tuple[ChunkRequest, int, int]]] = []
         payload = 0
-        for ri, rr in enumerate(rows):
-            off = 0
-            row_layout = []
-            for gi, r in enumerate(rr):
-                m = r.data.size
-                batch[ri, off:off + m] = r.data
-                sep[ri, off:off + m] = off + m
-                ends[ri, gi] = off + m
-                row_layout.append((r, off, off + m))
-                off += m
-            sep[ri, off:] = off  # padding: its own (empty) tail segment
-            ends[ri, len(rr):] = off  # pad entries carry the payload end
-            layout.append(row_layout)
-            payload += off
+        with span("sched.pack", bucket=S, rows=R, packed=1):
+            batch = np.zeros((R, S), dtype=np.uint8)
+            sep = np.zeros((R, S), dtype=np.int32)
+            ends = np.zeros((R, G), dtype=np.int32)
+            for ri, rr in enumerate(rows):
+                off = 0
+                row_layout = []
+                for gi, r in enumerate(rr):
+                    m = r.data.size
+                    batch[ri, off:off + m] = r.data
+                    sep[ri, off:off + m] = off + m
+                    ends[ri, gi] = off + m
+                    row_layout.append((r, off, off + m))
+                    off += m
+                sep[ri, off:] = off  # padding: its own (empty) tail segment
+                ends[ri, len(rr):] = off  # pad entries carry the payload end
+                layout.append(row_layout)
+                payload += off
         # per-segment bound on chunks: sum of per-stream max_chunks_for
         mc = S // self.params.min_size + 2 * G + 2
         key = ("packed", G)
@@ -823,9 +827,10 @@ class ChunkScheduler:
                   padded_fps: np.ndarray | None) -> ChunkResult:
         """Trim a padded-run boundary list to the exact per-stream result."""
         t0 = time.perf_counter()
-        bounds, fps, lengths, tail_bytes = _trim_exact(
-            req.data, padded, padded_fps, self.params
-        )
+        with span("sched.tail", bytes=req.data.size):
+            bounds, fps, lengths, tail_bytes = _trim_exact(
+                req.data, padded, padded_fps, self.params
+            )
         if tail_bytes:
             # tail_s counts only redos that did work: the kept-boundary
             # trim is O(chunks) bookkeeping, the oracle re-chunk is the

@@ -53,7 +53,6 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import time
 from collections import Counter
 from typing import List, Optional, Sequence
 
@@ -284,11 +283,8 @@ class ShardedDedupService(ServiceBase):
         # device-side error — the submitted names are no longer pending, so
         # they must stop blocking resubmission
         with self._request("flush"):
-            t0 = time.perf_counter()
             with span("service.flush") as sp:
-                out = self._flush(sp)
-            self.obs.observe("service.flush_s", time.perf_counter() - t0)
-            return out
+                return self._flush(sp)
 
     def _flush(self, sp) -> List[ObjectStat]:
         tail0 = self.scheduler.stats.tail_s
@@ -495,7 +491,6 @@ class ShardedDedupService(ServiceBase):
         """
         r = self.recipes.get(name)
         with self._request("get", object=name):
-            t0 = time.perf_counter()
             with span("service.get", object=name, bytes=r.size):
                 with self._phase("routing"):
                     owners = self._recipe_shards(r)
@@ -523,7 +518,6 @@ class ShardedDedupService(ServiceBase):
                     data = verify_restore(
                         r, b"".join(parts)  # type: ignore[arg-type]
                     )
-            self.obs.observe("service.get_s", time.perf_counter() - t0)
             self.obs.inc("restore.objects")
             self.obs.inc("restore.bytes", r.size)
             return data

@@ -41,6 +41,7 @@ from repro.obs import (
     scope,
     span,
 )
+from repro.obs import trace as obs_trace
 from repro.service import DedupService, ShardedDedupService
 
 P = SeqCDCParams(avg_size=256, seq_length=3, skip_trigger=6, skip_size=32,
@@ -146,8 +147,11 @@ class TestTracing:
         assert not enabled()
         sp = span("x", a=1)
         with sp as s:
-            s["b"] = 2  # attrs on the null span are dropped, not errors
-        assert span("y") is span("z")  # the shared no-op instance
+            s["b"] = 2  # attrs on a span that writes nothing are dropped
+        # the service installed the profiler hook at import; without one,
+        # a span with tracing off is the shared no-op instance
+        monkeypatch.setattr(obs_trace, "_annotator", None)
+        assert span("y") is span("z")
 
     def test_jsonl_records(self, tmp_path, monkeypatch):
         trace = tmp_path / "t.jsonl"
@@ -226,11 +230,12 @@ class TestServiceMetrics:
 
     def test_flush_and_get_latency_histograms(self, rng):
         svc = _mk_service()
-        svc.put("a", rng.integers(0, 256, 30000, dtype=np.uint8))
+        svc.submit("a", rng.integers(0, 256, 30000, dtype=np.uint8))
+        svc.flush()
         svc.get("a")
         hists = svc.metrics()["service"]["histograms"]
-        assert hists["service.flush_s"]["count"] == 1
-        assert hists["service.get_s"]["count"] == 1
+        assert hists[labeled("req.total_s", op="flush")]["count"] == 1
+        assert hists[labeled("req.total_s", op="get")]["count"] == 1
 
     def test_registries_are_per_service(self, rng):
         a, b = _mk_service(), _mk_service()
@@ -678,6 +683,114 @@ class TestRequestAttribution:
             root["wall_s"], abs=0.05)
         # every other span this request emitted descends from the root
         assert all(r["trace_id"] == root["trace_id"] for r in recs)
+
+
+# -- the profiler timeline and the commit's counters -----------------------------
+class _FakeAnnotator:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation entered and checks that it is left."""
+
+    def __init__(self):
+        self.entered = []
+        self.open = 0
+
+    def __call__(self, name):
+        fake = self
+
+        class _Annotation:
+            def __enter__(self):
+                fake.entered.append(name)
+                fake.open += 1
+
+            def __exit__(self, *exc):
+                fake.open -= 1
+                return False
+
+        return _Annotation()
+
+
+class TestProfilerTimeline:
+    @pytest.mark.parametrize("traced", [False, True])
+    def test_every_span_and_phase_is_annotated(self, rng, tmp_path,
+                                               monkeypatch, traced):
+        fake = _FakeAnnotator()
+        monkeypatch.setattr(obs_trace, "_annotator", fake)
+        sink = tmp_path / "t.jsonl"
+        if traced:
+            monkeypatch.setenv("REPRO_TRACE", str(sink))
+        else:
+            monkeypatch.delenv("REPRO_TRACE", raising=False)
+        svc = DedupService.open(str(tmp_path / "depot"), params=P, slots=4,
+                                min_bucket=1024)
+        for i, v in enumerate(_corpus(rng)):
+            svc.submit(f"o{i}", v)
+        svc.flush()
+        svc.get("o0")
+        assert fake.open == 0
+        names = set(fake.entered)
+        assert {"repro.request", "repro.service.flush", "repro.sched.pack",
+                "repro.sched.dispatch", "repro.sched.tail",
+                "repro.commit.object", "repro.sync.recipes",
+                "repro.sync.manifest", "repro.service.get"} <= names
+        h = svc.obs.snapshot()["histograms"]
+        phases = {k.split("phase=", 1)[1][:-1] for k in h
+                  if k.startswith("req.latency_s{")}
+        # "other" is the clock's remainder and "tail" is moved in after
+        # the fact; every phase entered is annotated
+        assert {f"repro.phase.{p}" for p in phases - {"other", "tail"}} \
+            <= names
+        assert {"repro.phase.commit", "repro.phase.fp", "repro.phase.rpc",
+                "repro.phase.verify"} <= names
+        if traced:
+            recs = [json.loads(line) for line in sink.read_text().splitlines()]
+            assert {f"repro.{r['name']}" for r in recs} <= names
+        else:
+            assert not sink.exists()
+
+    def test_obs_imports_no_jax(self):
+        import subprocess
+        import sys
+
+        code = ("import sys, repro.obs; "
+                "assert 'jax' not in sys.modules, 'jax'; "
+                "assert 'numpy' not in sys.modules, 'numpy'")
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, PYTHONPATH=src)
+        subprocess.run([sys.executable, "-c", code], check=True, env=env)
+
+
+class TestCommitCounters:
+    def test_block_writes_only_for_new_chunks(self, rng, tmp_path):
+        data = rng.integers(0, 256, 60000, dtype=np.uint8)
+        root = str(tmp_path / "depot")
+        first = DedupService.open(root, params=P, slots=4, min_bucket=1024)
+        first.submit("a", data)
+        first.flush()
+        c = first.obs.snapshot()["counters"]
+        assert c["ingest.dedup_hit_chunks"] < c["ingest.chunks"]
+        assert c["store.block_write_s"] > 0
+        assert c["store.key_hash_s"] > 0 and c["commit.digest_s"] > 0
+        again = DedupService.open(root, params=P, slots=4, min_bucket=1024)
+        again.submit("b", data)
+        again.flush()
+        c = again.obs.snapshot()["counters"]
+        assert c["ingest.dedup_hit_chunks"] == c["ingest.chunks"] > 0
+        assert c["store.block_write_s"] == 0
+        assert c["store.key_hash_s"] > 0 and c["commit.digest_s"] > 0
+
+    def test_counters_fit_inside_the_commit_phase(self, rng, tmp_path):
+        svc = DedupService.open(str(tmp_path / "depot"), params=P, slots=4,
+                                min_bucket=1024)
+        for i, v in enumerate(_corpus(rng)):
+            svc.submit(f"o{i}", v)
+        svc.flush()
+        snap = svc.obs.snapshot()
+        c = snap["counters"]
+        commit = snap["histograms"][labeled(
+            "req.latency_s", op="flush", phase="commit")]["sum"]
+        parts = (c["store.key_hash_s"] + c["store.block_write_s"]
+                 + c["commit.digest_s"])
+        assert 0 < parts <= commit
 
 
 # -- the wire: causal trees across processes -------------------------------------
