@@ -21,9 +21,12 @@ deleting everything and running ``gc`` returns the store to zero.
 
 Then each kernel path the scheduler can select ingests the same corpus and
 must give recipes (chunk keys, lengths, packed fingerprints) bit-identical
-to the default path's: ``pipeline_impl="fused"``, ``mask_impl="pallas"`` with
-``fp_impl="pallas"``, and ``packing_impl="segments"`` with fused.  On the
-chip each must run compiled: the dispatched program holds a Mosaic kernel.
+to the default path's: ``pipeline_impl="fused"``, ``mask_impl="pallas"``
+with ``fp_impl="pallas"``, ``packing_impl="segments"`` with fused, and the
+reference fingerprint chain (``fp_impl="reference"``).  The
+default path leaves ``fp_impl`` to the platform, which on a TPU is the
+fingerprint kernel.  On the chip every path that holds a kernel must run it
+compiled: the dispatched program holds a Mosaic kernel.
 
 Last, ``ShardedDedupService.open(root, 2, transport="remote")`` spawns two
 shard-server processes from this process, which holds the chip, ingests the
@@ -70,16 +73,18 @@ class Sizes:
 SMALL_LO, SMALL_HI = 4 << 10, 96 << 10
 
 
-#: the served path's defaults, pinned so no environment variable moves them
-DEFAULT_PATH = dict(mask_impl="jnp", step_impl="wide", fp_impl="reference",
+#: the served path's defaults, pinned so no environment variable moves them;
+#: ``fp_impl`` is left to the scheduler, which chooses it by platform
+DEFAULT_PATH = dict(mask_impl="jnp", step_impl="wide", fp_impl=None,
                     pipeline_impl="split", packing_impl="off")
-#: every kernel path the scheduler can select, each held to the default
-KERNEL_PATHS = {
+#: every other path the scheduler can select, each held to the default
+OTHER_PATHS = {
     "fused": dict(DEFAULT_PATH, pipeline_impl="fused"),
     "pallas-masks-fps": dict(DEFAULT_PATH, mask_impl="pallas",
                              fp_impl="pallas"),
     "packed-fused": dict(DEFAULT_PATH, pipeline_impl="fused",
                          packing_impl="segments"),
+    "reference-fps": dict(DEFAULT_PATH, fp_impl="reference"),
 }
 
 
@@ -227,8 +232,14 @@ def check_gc_to_zero(svc, names):
           f"store not empty after delete + gc: {stats}")
 
 
-def check_compiled(params, kernel_path: dict):
-    """The kernel path's device program holds a Mosaic kernel (on the chip
+def holds_kernel(sched) -> bool:
+    """Whether the scheduler's resolved path dispatches a Pallas kernel."""
+    return ("pallas" in (sched.mask_impl, sched.fp_impl)
+            or sched.pipeline_impl == "fused")
+
+
+def check_compiled(sched):
+    """The scheduler's device program holds a Mosaic kernel (on the chip
     the kernels compile; nothing falls back to the interpreter)."""
     import jax
     import jax.numpy as jnp
@@ -239,12 +250,14 @@ def check_compiled(params, kernel_path: dict):
 
     check(not ops._interpret(), "Pallas kernels would run interpreted")
     bucket = 1 << 14
-    kw = {k: v for k, v in kernel_path.items() if k != "packing_impl"}
+    path = dict(mask_impl=sched.mask_impl, step_impl=sched.step_impl,
+                fp_impl=sched.fp_impl, pipeline_impl=sched.pipeline_impl)
     lowered = jax.jit(lambda x: _device_chunk(
-        x, p=params, mc=max_chunks_for(bucket, params), with_fp=True, **kw,
+        x, p=sched.params, mc=max_chunks_for(bucket, sched.params),
+        with_fp=True, **path,
     )).lower(jax.ShapeDtypeStruct((1, bucket), jnp.uint8))
     check("tpu_custom_call" in lowered.as_text(),
-          f"no Mosaic kernel in the {kernel_path} device program")
+          f"no Mosaic kernel in the {path} device program")
 
 
 # -- phases ---------------------------------------------------------------------
@@ -260,6 +273,11 @@ def run_one_chip(sizes: Sizes, root: str, *, require_compiled: bool = True):
 
     t0, mark = time.perf_counter(), compiles.mark()
     svc = DedupService.open(os.path.join(root, "default"), **DEFAULT_PATH)
+    if require_compiled:
+        check(svc.scheduler.fp_impl == "pallas",
+              f"the default path on a TPU fingerprints with "
+              f"{svc.scheduler.fp_impl!r}, not the kernel")
+        check_compiled(svc.scheduler)
     ingest(svc, corpus)
     stats = check_exact(svc, corpus, svc.params)
     want = recipes_of(svc)
@@ -268,14 +286,15 @@ def run_one_chip(sizes: Sizes, root: str, *, require_compiled: bool = True):
            stored_bytes=stats.stored_bytes,
            unique_chunks=stats.unique_chunks,
            dedup_ratio=round(stats.dedup_ratio, 4),
+           fp_impl=svc.scheduler.fp_impl,
            buckets=sorted({svc.scheduler._bucket_for(d.size)
                            for _, d in corpus}))
 
-    for name, path in KERNEL_PATHS.items():
+    for name, path in OTHER_PATHS.items():
         t0, mark = time.perf_counter(), compiles.mark()
         svc = DedupService.open(os.path.join(root, name), **path)
-        if require_compiled:
-            check_compiled(svc.params, path)
+        if require_compiled and holds_kernel(svc.scheduler):
+            check_compiled(svc.scheduler)
         ingest(svc, corpus)
         got = recipes_of(svc)
         for obj, rec in want.items():

@@ -32,7 +32,8 @@ from repro.core import stages
 #: backend for :func:`chunk_fingerprints`: the jnp ``searchsorted``/gather/
 #: ``segment_sum`` chain ("reference") or the fused Pallas kernel
 #: (kernels/fingerprint.py) — bit-identical, guarded by the scheduler's
-#: first-dispatch cross-check (docs/KERNELS.md)
+#: first-dispatch cross-check (docs/KERNELS.md); the service scheduler runs
+#: the kernel on a TPU and the reference chain elsewhere
 FpImpl = Literal["reference", "pallas"]
 
 P31 = np.uint32((1 << 31) - 1)

@@ -26,6 +26,10 @@ and per tile, for both generators in one pass:
    power table, and the fingerprint against the latched ``P(s)`` of the
    previous chunk, written with a masked store.
 
+The service scheduler runs this kernel on a TPU (its default ``fp_impl``
+there) and the reference chain on the CPU, where the kernel would run in
+the Pallas interpreter.
+
 Output is bit-identical to ``chunk_fingerprints(..., fp_impl="reference")``
 and to ``fingerprints_numpy`` — tests/test_fingerprint_kernel.py and the
 scheduler's first-dispatch cross-check (docs/KERNELS.md) enforce it;

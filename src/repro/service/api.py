@@ -331,7 +331,7 @@ class DedupService(ServiceBase):
         recipes: Optional[RecipeTable] = None,
         mask_impl: str = "jnp",
         step_impl: str = "wide",
-        fp_impl: str = "reference",
+        fp_impl: str | None = None,
         pipeline_impl: str | None = None,
         packing_impl: str | None = None,
         with_fingerprints: bool = True,

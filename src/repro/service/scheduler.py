@@ -27,9 +27,10 @@ device-batch throughput.
 
 Both device stages have selectable backends (docs/KERNELS.md):
 ``mask_impl`` for the phase-1 bitmaps and ``fp_impl`` for chunk hashing
-(the fused Pallas fingerprint kernel vs the gather/segment_sum reference),
-each guarded by a first-dispatch bit-identity cross-check
-(``cross_check_masks`` / ``cross_check_fps``).  Above both sits
+(the fused Pallas fingerprint kernel vs the gather/segment_sum reference;
+left unset, ``fp_impl`` follows the platform: the kernel on a TPU, the
+reference chain elsewhere), each guarded by a first-dispatch bit-identity
+cross-check (``cross_check_masks`` / ``cross_check_fps``).  Above both sits
 ``pipeline_impl``: ``"split"`` runs the stages as separate dispatches,
 ``"fused"`` collapses mask + boundary scan + fingerprints into the single
 ``kernels/fused_pipeline.py`` dispatch (one byte read instead of three),
@@ -105,6 +106,13 @@ def _default_pipeline_impl() -> str:
 def _default_packing_impl() -> str:
     """``REPRO_PACKING_IMPL`` (CI's packing-on leg sets it), else off."""
     return os.environ.get("REPRO_PACKING_IMPL", "off")
+
+
+def _default_fp_impl() -> str:
+    """The Pallas fingerprint kernel on a TPU, where it compiles to Mosaic;
+    the jnp reference chain elsewhere (on the CPU the kernel would run in
+    the Pallas interpreter).  Both give bit-identical fingerprints."""
+    return "pallas" if jax.default_backend() == "tpu" else "reference"
 
 
 def _run_fused(x, p, mc):
@@ -323,7 +331,7 @@ class ChunkScheduler:
         max_batch_bytes: int = 8 << 20,
         mask_impl: MaskImpl = "jnp",
         step_impl: StepImpl = "wide",
-        fp_impl: FpImpl = "reference",
+        fp_impl: FpImpl | None = None,
         pipeline_impl: PipelineImpl | None = None,
         packing_impl: PackingImpl | None = None,
         with_fingerprints: bool = True,
@@ -346,7 +354,9 @@ class ChunkScheduler:
         self.min_bucket = max(min_bucket, self.params.max_size)
         self.mask_impl = mask_impl
         self.step_impl = step_impl
-        self.fp_impl = fp_impl
+        # resolved once: the dispatch histogram's ``fp=`` label then names
+        # the path that ran
+        self.fp_impl = fp_impl if fp_impl is not None else _default_fp_impl()
         if pipeline_impl is None:
             pipeline_impl = _default_pipeline_impl()
         if pipeline_impl not in PIPELINE_IMPLS:

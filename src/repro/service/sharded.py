@@ -100,7 +100,7 @@ class ShardedDedupService(ServiceBase):
         recipes: Optional[RecipeTable] = None,
         mask_impl: str = "jnp",
         step_impl: str = "wide",
-        fp_impl: str = "reference",
+        fp_impl: str | None = None,
         pipeline_impl: str | None = None,
         packing_impl: str | None = None,
         cross_check_masks: bool = False,

@@ -126,9 +126,10 @@ def test_chip_smoke_one_chip_phases_rehearsal(tmp_path, capsys):
     chip_smoke.run_one_chip(TINY, str(tmp_path), require_compiled=False)
     lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
     assert [x["phase"] for x in lines] == [
-        "default", *chip_smoke.KERNEL_PATHS, "remote-2-shards"]
-    assert lines[3]["packed_streams"] > 0  # the packed path really packed
-    assert len({x["dedup_ratio"] for x in lines[:4]}) == 1
+        "default", *chip_smoke.OTHER_PATHS, "remote-2-shards"]
+    by_phase = {x["phase"]: x for x in lines}
+    assert by_phase["packed-fused"]["packed_streams"] > 0  # really packed
+    assert len({x["dedup_ratio"] for x in lines[:-1]}) == 1
 
 
 @pytest.mark.timeout(600)

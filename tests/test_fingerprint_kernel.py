@@ -140,6 +140,46 @@ def test_chunker_bounds_layout_parity(rng):
     np.testing.assert_array_equal(np.asarray(len_k), np.asarray(len_r))
 
 
+#: the benchmark's chunking: SeqCDC Table I at an 8 KiB average (4-16 KiB)
+TABLE_I_8K = SeqCDCParams(avg_size=8192, seq_length=5, skip_trigger=50,
+                          skip_size=256, min_size=4096, max_size=16384)
+
+
+@pytest.mark.parametrize("width", [16 << 10, 96 << 10],
+                         ids=["16KiB-one-tile", "96KiB-two-tiles"])
+def test_vmapped_kernel_parity_at_table_i_8k(width, rng):
+    """The scheduler's batched form at the benchmark's chunking: a full row
+    (its last chunk ends at the row's width), a short stream zero-padded to
+    the width, and a zero-count padding row, through the vmapped kernel and
+    the vmapped reference chain."""
+    import jax
+
+    from repro.core.seqcdc import boundaries_batch
+
+    short = width // 3 + 123
+    x = np.zeros((3, width), dtype=np.uint8)
+    x[0] = rng.integers(0, 256, width, dtype=np.uint8)
+    x[1, :short] = rng.integers(0, 256, short, dtype=np.uint8)
+    mc = max_chunks_for(width, TABLE_I_8K)
+    b, c = boundaries_batch(jnp.asarray(x), TABLE_I_8K, max_chunks=mc)
+    b = np.asarray(b).copy()
+    c = np.asarray(c).copy()
+    assert b[0, c[0] - 1] == width
+    b[2], c[2] = _SENTINEL, 0  # a padding row: no chunks
+    args = (jnp.asarray(x), jnp.asarray(b), jnp.asarray(c))
+    fp_k, len_k = jax.vmap(lambda d, bb, cc: fingerprint_pallas(
+        d, bb, cc, max_chunks=mc, interpret=True))(*args)
+    fp_r, len_r = jax.vmap(lambda d, bb, cc: chunk_fingerprints(
+        d, bb, cc, max_chunks=mc, fp_impl="reference"))(*args)
+    np.testing.assert_array_equal(np.asarray(fp_k), np.asarray(fp_r))
+    np.testing.assert_array_equal(np.asarray(len_k), np.asarray(len_r))
+    assert not np.asarray(fp_k)[2].any() and not np.asarray(len_k)[2].any()
+    for row in range(2):
+        cuts = b[row, : c[row]]
+        np.testing.assert_array_equal(np.asarray(fp_k)[row, : c[row]],
+                                      fingerprints_numpy(x[row], cuts))
+
+
 # -- the scheduler hot path -----------------------------------------------------
 
 def test_scheduler_fp_pallas_bit_identity(rng):
@@ -158,6 +198,53 @@ def test_scheduler_fp_pallas_bit_identity(rng):
         assert got[r.tag].bounds.tolist() == r.bounds.tolist()
         np.testing.assert_array_equal(got[r.tag].fps, r.fps)
     assert sched._fp_checked_buckets  # the guard actually ran
+
+
+# -- the platform chooses the served path ----------------------------------
+
+
+def _backend(monkeypatch, name: str):
+    """Make ``jax.default_backend()`` report ``name`` (nothing dispatches)."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: name)
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", "pallas"),
+                                          ("cpu", "reference")])
+def test_fp_impl_follows_platform(backend, want, monkeypatch):
+    """Unset, ``fp_impl`` resolves once from the backend: the kernel on a
+    TPU, the reference chain elsewhere; the services pass None through."""
+    from repro.service import DedupService, ShardedDedupService
+
+    _backend(monkeypatch, backend)
+    assert ChunkScheduler(P).fp_impl == want
+    assert ChunkScheduler(P, fp_impl=None).fp_impl == want
+    assert DedupService(params=P).scheduler.fp_impl == want
+    with ShardedDedupService(2, params=P) as svc:
+        assert svc.scheduler.fp_impl == want
+
+
+def test_fp_impl_reference_under_cpu_backend():
+    """The tier-1 backend itself (no monkeypatch) resolves to the
+    reference chain."""
+    import jax
+
+    assert jax.default_backend() == "cpu"
+    assert ChunkScheduler(P).fp_impl == "reference"
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("explicit", ["reference", "pallas"])
+def test_explicit_fp_impl_wins(backend, explicit, monkeypatch):
+    from repro.service import DedupService, ShardedDedupService
+
+    _backend(monkeypatch, backend)
+    assert ChunkScheduler(P, fp_impl=explicit).fp_impl == explicit
+    assert DedupService(params=P, fp_impl=explicit).scheduler.fp_impl \
+        == explicit
+    with ShardedDedupService(2, params=P, fp_impl=explicit) as svc:
+        assert svc.scheduler.fp_impl == explicit
 
 
 def test_fingerprint_divergence_raises(rng, monkeypatch):

@@ -228,6 +228,24 @@ class TestServiceMetrics:
         assert occ, "no per-bucket occupancy gauge was set"
         assert all(0 < snap["gauges"][g] <= 1 for g in occ)
 
+    def test_dispatch_label_names_resolved_fp_impl(self, rng, monkeypatch):
+        """The ``fp=`` label names the fingerprint path that ran: the
+        reference chain on the CPU, the kernel where the backend is a TPU
+        (resolved at construction; nothing is dispatched there)."""
+        import jax
+
+        svc = _mk_service()
+        svc.put("a", rng.integers(0, 256, 5000, dtype=np.uint8))
+        hists = svc.metrics()["service"]["histograms"]
+        assert [h for h in hists if h.startswith("sched.dispatch_s{")] == [
+            labeled("sched.dispatch_s", pipeline=svc.scheduler.pipeline_impl,
+                    mask="jnp", fp="reference")]
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        sched = _mk_service().scheduler
+        assert sched._dispatch_hist == labeled(
+            "sched.dispatch_s", pipeline=sched.pipeline_impl, mask="jnp",
+            fp="pallas")
+
     def test_flush_and_get_latency_histograms(self, rng):
         svc = _mk_service()
         svc.submit("a", rng.integers(0, 256, 30000, dtype=np.uint8))

@@ -73,21 +73,26 @@ def _bytes(one_chip, shape, dtype=jnp.uint8):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def test_default_device_chunk_fits_hbm(one_chip):
-    """The default served path (jnp masks, wide scan, reference fps) at
-    8 x 1 MiB: plain XLA, with its temporaries inside one chip's HBM."""
-    from repro.service.scheduler import _device_chunk
+def test_default_device_chunk_fits_hbm(one_chip, monkeypatch):
+    """The default served path on a TPU (jnp masks, wide scan, and the
+    fingerprint kernel the scheduler resolves there) at 8 x 1 MiB: XLA
+    around one Mosaic kernel, with its temporaries inside one chip's HBM."""
+    from repro.service.scheduler import ChunkScheduler, _device_chunk
 
+    # what the scheduler and the kernel wrappers see on a TPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sched = ChunkScheduler(P)
+    assert sched.fp_impl == "pallas"
     mc = max_chunks_for(ROW_BYTES, P)
     compiled = _compile(
-        lambda x: _device_chunk(x, p=P, mc=mc, mask_impl="jnp",
-                                step_impl="wide", with_fp=True,
-                                fp_impl="reference", pipeline_impl="split"),
+        lambda x: _device_chunk(x, p=P, mc=mc, mask_impl=sched.mask_impl,
+                                step_impl=sched.step_impl, with_fp=True,
+                                fp_impl=sched.fp_impl, pipeline_impl="split"),
         _bytes(one_chip, (ROWS, ROW_BYTES)),
     )
     mem = compiled.memory_analysis()
     assert 0 < mem.temp_size_in_bytes < V5E_HBM_BYTES
-    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("kernel_path", [
