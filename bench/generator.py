@@ -1,73 +1,63 @@
-"""The benchmark's one traffic generator: versions of a file tree, put as
-flush groups, with restores drawn between them.
+"""The benchmark's one traffic generator: versions of a tree, put as flush
+groups, with restores drawn between them.
 
-A configuration file says what a tree is; a traffic file says how a client
-puts its versions and what it reads back.  Everything is drawn from the
-seed: version ``k`` is the same bytes for the same seed however many
-versions a run gets through, because each version draws from random streams
-of its own.  The seed draws the bytes and the order of the gets, never the
-work: every seed puts the same sizes under the same paths in the same flush
-groups, and gets the same objects the same number of times.
+A configuration file says what a tree is and which model makes it; a
+traffic file says how a client puts its versions and what it reads back.
+Everything is drawn from the seed: version ``k`` is the same bytes for the
+same seed however many versions a run gets through, because each version
+draws from random streams of its own.  The seed draws the bytes and the
+order of the gets, never the work: every seed puts the same sizes under the
+same paths in the same flush groups, and gets the same objects the same
+number of times.
 
-A tree (configuration keys): ``files`` objects named ``d<dir>/f<id>`` over
-``dirs`` directories, of random bytes, whose sizes sit at evenly spaced
-quantiles of a lognormal (``size_log_mean``, ``size_log_sigma``; natural
-log of bytes), shuffled over the paths in an order of each version's own
-that is the same for every seed.
+A tree model is ``models/<model>.py``, named by the configuration's
+``model`` key (``file_tree`` where the key is absent), found by name: its
+``Model(config, seed)`` has ``sizes()``, every object size a version can
+hold (the warm-up compiles the device shapes of these), and
+``tree(version)``, the version's ``{path: uint8 array}``.  A model draws
+from :func:`rng`, one stream per version and purpose: ``STRUCTURE`` with the
+seed replaced by ``FIXED`` for what every seed shares (sizes, paths),
+``CONTENT`` for the bytes.
 
 Traffic keys: ``name`` (the object name, formatted with ``version`` and
 ``path``); ``next``: ``"same"`` (every version is version 0 again: a full
 backup of a tree that has not changed) or ``"new"`` (every version is a tree
 of its own: the first full backup of another machine); ``group_max_objects``
 / ``group_max_bytes`` (a flush group closes at either; ``null`` for no
-limit); ``gets_per_group`` and ``get_zipf`` (after each flush group, that
-many ``get`` calls of objects of the latest version whose every object has
-been flushed.  A version's gets are a fixed multiset: each object is read
-its Zipf share (popularity of that constant) of them, rounded by largest
-remainder, with popularity ranks a fixed shuffle of the objects' size
-order; the seed shuffles that multiset and deals it out to the groups).
+limit); ``gets_per_group``, ``get_zipf`` and ``get_versions`` (after each
+flush group, that many ``get`` calls of the objects of the last
+``get_versions`` (default 1) versions before the one being put, all of whose
+objects have been flushed.  A version's gets are a fixed multiset: each of
+those objects is read its Zipf share (popularity of that constant) of them,
+rounded by largest remainder, with popularity ranks a fixed shuffle of the
+objects' order by size and name; the seed shuffles that multiset and deals
+it out to the groups).  The set-up puts versions ``0 .. get_versions - 1``
+and the measured window starts at version ``get_versions``.
 """
 from __future__ import annotations
 
 import dataclasses
-import statistics
 import time
 from typing import Dict, Iterator, List, Tuple
 
 import numpy as np
 
+from byname import load
+
 #: random streams a version draws from, each its own
-_STRUCTURE, _CONTENT, _GETS = 1, 2, 3
+STRUCTURE, CONTENT, GETS = 1, 2, 3
 #: stands for the seed in the streams that must not depend on it
-_FIXED = 0x5EEDF1EE
+FIXED = 0x5EEDF1EE
 
 
-def _rng(seed: int, stream: int, version: int, part: int = 0):
+def rng(seed: int, stream: int, version: int, part: int = 0):
     return np.random.default_rng(
         np.random.SeedSequence([seed & ((1 << 64) - 1), stream, version, part]))
 
 
-class FileTree:
-    def __init__(self, config: dict, seed: int):
-        self.cfg, self.seed = config, seed
-
-    def sizes(self) -> np.ndarray:
-        """The tree's sizes at evenly spaced quantiles of the lognormal, in
-        increasing order (at least one byte)."""
-        c = self.cfg
-        count = int(c["files"])
-        dist = statistics.NormalDist(float(c["size_log_mean"]),
-                                     float(c["size_log_sigma"]))
-        raw = np.exp([dist.inv_cdf((i + 0.5) / count) for i in range(count)])
-        return np.maximum(raw, 1).astype(np.int64)
-
-    def tree(self, version: int) -> Dict[str, np.ndarray]:
-        sizes = _rng(_FIXED, _STRUCTURE, version).permutation(self.sizes())
-        content = _rng(self.seed, _CONTENT, version)
-        fanout = int(self.cfg["dirs"])
-        return {f"d{i % fanout:02d}/f{i:06d}":
-                np.frombuffer(content.bytes(int(n)), dtype=np.uint8)
-                for i, n in enumerate(sizes.tolist())}
+def model(config: dict):
+    """The tree model module the configuration names."""
+    return load("models", config.get("model", "file_tree"))
 
 
 @dataclasses.dataclass
@@ -92,10 +82,15 @@ class Traffic:
         if traffic["next"] not in ("same", "new"):
             raise ValueError(f"next must be same or new, got "
                              f"{traffic['next']!r}")
-        self.model = FileTree(config, seed)
+        self.get_versions = int(traffic.get("get_versions", 1))
+        if self.get_versions < 1:
+            raise ValueError(f"get_versions must be at least 1, got "
+                             f"{self.get_versions}")
+        self.model = model(config).Model(config, seed)
         self._tree: Tuple[int, Dict[str, np.ndarray]] | None = None
-        #: the last version whose groups have all been yielded
-        self._readable: Dict[str, np.ndarray] = {}
+        #: (version, files) of the last ``get_versions`` versions whose
+        #: groups have all been yielded
+        self._readable: List[Tuple[int, Dict[str, np.ndarray]]] = []
         #: every object put so far (name -> bytes), for the checks
         self.objects: Dict[str, np.ndarray] = {}
 
@@ -140,21 +135,22 @@ class Traffic:
         reads = np.floor(share).astype(np.int64)
         rest = count * groups - int(reads.sum())
         reads[np.argsort(-(share - reads), kind="stable")[:rest]] += 1
-        order = _rng(self.seed, _GETS, version).permutation(
+        order = rng(self.seed, GETS, version).permutation(
             np.repeat(np.arange(len(paths)), reads))
         names = [paths[int(rank[i])] for i in order.tolist()]
         return [names[g * count:(g + 1) * count] for g in range(groups)]
 
     def groups(self, start: int, stop: int | None = None) -> Iterator[Group]:
         """Flush groups of versions ``start`` .. ``stop - 1`` (no end when
-        ``stop`` is None).  Gets read the version before the one being put,
-        whose objects are all flushed once its last group has been."""
+        ``stop`` is None).  Gets read the ``get_versions`` versions before
+        the one being put, whose objects are all flushed once their last
+        groups have been."""
         k = start
         while stop is None or k < stop:
             t0 = time.perf_counter()
             files = self.version(k)
-            prev = {self.name(k - 1, p): d
-                    for p, d in self._readable.items()}
+            prev = {self.name(j, p): d
+                    for j, tree in self._readable for p, d in tree.items()}
             gen_s = time.perf_counter() - t0
             split = list(self._split(files))
             plan = self._get_plan(k, prev, len(split))
@@ -167,5 +163,6 @@ class Traffic:
                 yield Group(k, puts, gets, gen_s + time.perf_counter() - t0,
                             last=gi == len(split) - 1)
                 gen_s = 0.0
-            self._readable = files
+            self._readable = (self._readable
+                              + [(k, files)])[-self.get_versions:]
             k += 1

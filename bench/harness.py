@@ -2,12 +2,17 @@
 the dedup service's default served path, check what it produced against the
 plain reference, and reduce the run to the cell's metrics.
 
-Everything a cell is made of is found by name under this directory:
-``BENCHMARK.json`` (at the checkout's root) names the cell's configuration
-file and traffic mix, ``traffic/<traffic>.json`` holds the mix's parameters
-for the one generator (``generator.py``), and every metric is read by
-``metrics/<name>.py``, whose ``read(rec)`` returns the number or None when
-the run has nothing for it to read.  ``rec`` holds the run's records:
+Everything a cell is made of is found by name under this directory, so a
+new configuration, traffic mix, tree model or metric comes as new files and
+new entries of ``BENCHMARK.json`` (at the checkout's root), which names the
+cell's configuration file and traffic mix: ``traffic/<traffic>.json`` holds
+the mix's parameters for the one generator (``generator.py``), the
+configuration's ``model`` names its tree model ``models/<model>.py``, and
+every metric is read by ``metrics/<name>.py``, whose ``read(rec)`` returns
+the number or None when the run has nothing for it to read.  Beside it a
+metric file keeps ``CASE = (fields, value)``: the fields it adds to the
+handmade record of ``tests/test_bench_metrics.py`` and what ``read`` returns
+on the result.  ``rec`` holds the run's records:
 
 * ``setup_s``; ``ingest_s`` and ``ingest_bytes`` (wall seconds from the
   first ``submit`` of each flush group to the return of its ``flush``, and
@@ -16,17 +21,23 @@ the run has nothing for it to read.  ``rec`` holds the run's records:
   ``device_bytes``, ``tail_bytes``, ``payload_bytes``);
 * ``phases``: seconds of each request phase over the window, by operation
   (``phases["flush"]["commit"]``), from the service's phase clock;
-* ``trace``: the reduction of the profiler trace of the window
-  (``tracefile.py``), only in a traced run;
+* ``counters``: the window's delta of every counter of the service's
+  registry (``counters["store.block_write_s"]``); a counter the program
+  does not keep is absent;
+* ``trace``: only in a traced run, the reduction of the profiler trace of
+  the window, which a traced run makes one whole version long
+  (:func:`reduce_trace`): ``busy_s``, ``window_s``,
+  ``device_ops`` and ``idle_gaps`` (``tracefile.py``), ``stages`` (device
+  seconds of each named stage), ``idle_spans`` and ``idle_under`` (idle
+  seconds by program span, ``timeline.py``);
 * ``peaks``: the device's row of ``peaks.json``.
 
-Set-up puts version 0 of the traffic, then warms up every device shape the
-tree's object sizes can dispatch.
+Set-up puts versions ``0 .. get_versions - 1`` of the traffic, then warms
+up every device shape the tree's object sizes can dispatch.
 """
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 import os
 import sys
@@ -37,7 +48,9 @@ from typing import Dict, List
 import numpy as np
 
 import reference
+import timeline
 import tracefile
+from byname import load
 from generator import Traffic
 
 HERE = Path(__file__).resolve().parent
@@ -103,12 +116,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 
 def reader(metric: str):
-    path = HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load("metrics", metric).read
 
 
 def device_peaks(kind: str) -> dict:
@@ -174,6 +182,31 @@ def _phase_seconds(snapshot: dict) -> Dict[str, Dict[str, float]]:
 def _phase_delta(a, b):
     return {op: {ph: s - a.get(op, {}).get(ph, 0.0) for ph, s in phs.items()}
             for op, phs in b.items()}
+
+
+def counter_delta(a: dict, b: dict) -> Dict[str, float]:
+    """Each counter of snapshot ``b`` less its value in the earlier
+    snapshot ``a`` (0 where ``a`` lacks it)."""
+    return {k: v - a["counters"].get(k, 0) for k, v in b["counters"].items()}
+
+
+def reduce_trace(path: str) -> dict | None:
+    """The profiler trace under ``path``, read once (``timeline.parse``):
+    ``tracefile.reduce`` of its ``bench.*`` spans and device operations,
+    with ``stages``, ``idle_spans`` and ``idle_under`` of
+    ``timeline.reduce``; None when no operation ran on a device inside the
+    window."""
+    parsed = timeline.parse(path)
+    out = tracefile.reduce({
+        "spans": [s for s in parsed["spans"]
+                  if s[0].startswith(tracefile.SPAN_PREFIX)],
+        "devices": {plane: [op[:3] for op in ops]
+                    for plane, ops in parsed["devices"].items()}})
+    named = timeline.reduce(parsed)
+    if out is None or named is None:
+        return None
+    out.update({k: named[k] for k in ("stages", "idle_spans", "idle_under")})
+    return out
 
 
 def _sched(svc) -> Dict[str, int]:
@@ -345,7 +378,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     traffic = Traffic(cell.config, cell.traffic, seed)
     svc = DedupService.open(os.path.join(workdir, "depot"), params=params)
     acked: Dict[str, np.ndarray] = {}
-    for group in traffic.groups(0, 1):
+    for group in traffic.groups(0, traffic.get_versions):
         _ingest(svc, group)
         acked.update(group.puts)
     shapes = warm_up(params, traffic.model.sizes())
@@ -353,8 +386,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     log(json.dumps({"info": "setup", "setup_s": setup_s,
                     "warmed_shapes": shapes, **compiles.info()}))
 
-    groups = traffic.groups(1)
-    sched0, phases0 = _sched(svc), _phase_seconds(svc.obs.snapshot())
+    groups = traffic.groups(traffic.get_versions)
+    sched0, snap0 = _sched(svc), svc.obs.snapshot()
     compiles0 = compiles.count
     trace_dir = os.path.join(workdir, "trace")
     if trace:
@@ -363,12 +396,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         opts.host_tracer_level = 1
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        window = drive(svc, groups, seconds, acked, traffic.objects)
+        # a traced window is one version: the profiler's export and the
+        # trace's reading grow with the device operations it holds (about
+        # two minutes a version on a v5e), and a run has six minutes
+        window = drive(svc, groups, 0.0 if trace else seconds, acked,
+                       traffic.objects)
     finally:
         if trace:
             jax.profiler.stop_trace()
     in_window = compiles.count - compiles0
-    sched1, phases1 = _sched(svc), _phase_seconds(svc.obs.snapshot())
+    sched1, snap1 = _sched(svc), svc.obs.snapshot()
     memory_peak = _peak_memory(device)
     log(json.dumps({"info": "window", "wall_s": window.wall_s,
                     "groups": window.groups, "puts": window.puts,
@@ -384,13 +421,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         "ingest_bytes": window.ingest_bytes,
         "get_latencies_s": window.get_latencies_s,
         "sched": {k: sched1[k] - sched0[k] for k in sched1},
-        "phases": _phase_delta(phases0, phases1),
+        "phases": _phase_delta(_phase_seconds(snap0), _phase_seconds(snap1)),
+        "counters": counter_delta(snap0, snap1),
         "trace": None,
         "peaks": peaks,
     }
     if trace:
         t_read = time.perf_counter()
-        rec["trace"] = tracefile.reduce(tracefile.read(trace_dir))
+        rec["trace"] = reduce_trace(trace_dir)
         log(json.dumps({"info": "trace", "read_s":
                         time.perf_counter() - t_read,
                         "devices": (rec["trace"] or {}).get("devices")}))

@@ -11,3 +11,6 @@ def read(rec):
     if not t or not peaks or t["busy_s"] <= 0 or payload <= 0:
         return None
     return 100.0 * payload / float(peaks["hbm_bytes_per_s"]) / t["busy_s"]
+
+
+CASE = ({}, 100.0 * 819_000 / 819e9 / 0.25)

@@ -11,3 +11,6 @@ def read(rec):
     ph = rec["phases"]
     s = sum(ph.get(op, {}).get(p, 0.0) for op in OPS for p in ("commit", "fp"))
     return 100.0 * s / rec["ingest_s"]
+
+
+CASE = ({}, 100.0 * (1.0 + 0.2) / 4.0)

@@ -7,3 +7,6 @@ def read(rec):
     if not t or t["window_s"] <= 0:
         return None
     return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
+
+
+CASE = ({}, 100.0 * (1 - 0.25 / 1.0))

@@ -1,0 +1,18 @@
+"""Device kernels: seconds of the ``chunk.masks`` stage (the SeqCDC
+boundary masks) over the device's busy seconds in the traced window.  The
+trace reduction gives each busy instant to one stage (``timeline.py``), so
+the stages' shares sum to 100%."""
+
+STAGE = "masks"
+
+
+def read(rec):
+    t = rec["trace"]
+    if not t or t["busy_s"] <= 0 or STAGE not in t.get("stages", {}):
+        return None
+    return 100.0 * t["stages"][STAGE] / t["busy_s"]
+
+
+CASE = ({"trace": {"stages": {"fingerprint": 0.0075, "automaton": 0.24,
+                              "masks": 0.001, "other": 0.0015}}},
+        100.0 * 0.001 / 0.25)

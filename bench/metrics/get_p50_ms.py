@@ -5,3 +5,6 @@ import statistics
 def read(rec):
     lat = rec["get_latencies_s"]
     return statistics.median(lat) * 1e3 if lat else None
+
+
+CASE = ({}, 50.5)

@@ -8,3 +8,6 @@ def read(rec):
     if not lat:
         return None
     return lat[math.ceil(0.95 * len(lat)) - 1] * 1e3
+
+
+CASE = ({}, 95.0)

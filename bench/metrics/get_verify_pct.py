@@ -8,3 +8,6 @@ def read(rec):
     if total <= 0:
         return None
     return 100.0 * rec["phases"].get("get", {}).get("verify", 0.0) / total
+
+
+CASE = ({}, 100.0 * 0.0101 / 5.05)

@@ -7,3 +7,6 @@ def read(rec):
     if rec["ingest_s"] <= 0 or rec["ingest_bytes"] <= 0:
         return None
     return rec["ingest_bytes"] / 1e6 / rec["ingest_s"]
+
+
+CASE = ({}, 20e6 / 1e6 / 4.0)

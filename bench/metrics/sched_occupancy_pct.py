@@ -7,3 +7,6 @@ def read(rec):
     if s["device_bytes"] <= 0:
         return None
     return 100.0 * s["stream_bytes"] / s["device_bytes"]
+
+
+CASE = ({}, 100.0 * 600 / 1000)

@@ -7,3 +7,6 @@ def read(rec):
     if s["stream_bytes"] <= 0:
         return None
     return 100.0 * s["tail_bytes"] / s["stream_bytes"]
+
+
+CASE = ({}, 100.0 * 150 / 600)

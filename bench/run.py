@@ -7,7 +7,8 @@ and a traffic mix; the run builds its data from ``--seed``, ingests the
 set-up version into a fresh file-backed depot in a temporary directory,
 warms up the device shapes the mix uses, then drives whole backups of the
 tree through ``DedupService`` on its default served path for ``--seconds``
-(the backup in flight then finishes).
+(the backup in flight then finishes); with ``--trace 1`` it profiles one
+whole backup instead.
 After the window it compares every acknowledged object with the plain
 reference (``reference.py``).
 

@@ -3,7 +3,9 @@ comparison with the reference must come out false, naming the fault; the
 sound run must come out true."""
 import pytest
 
-from tiny import CELLS, run_tiny
+import harness
+from generator import Traffic
+from tiny import CELLS, run_tiny, tiny_cell
 
 CAUGHT_BY = {
     "tail": "fingerprints_differ",
@@ -13,12 +15,10 @@ CAUGHT_BY = {
     "fingerprint": "fingerprints_differ",
     "get": "gets_differ",
 }
-#: metrics of a run without a trace, by cell
-END_TO_END = {
-    "file-backup.weekly": {"ingest_MBps", "get_p50_ms", "get_p95_ms",
-                           "setup_s"},
-    "file-backup.first": {"ingest_MBps.first", "setup_s"},
-}
+
+
+def _gets(cell: str) -> bool:
+    return bool(tiny_cell(cell).traffic.get("gets_per_group"))
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -26,15 +26,17 @@ def test_sound_run_is_correct(cell):
     r = run_tiny(cell)
     assert r["correct"], r["checks"]
     assert r["checks"]["fingerprints_differ"]["of"] > 0
-    assert END_TO_END[cell] == set(r["metrics"])
+    assert set(r["metrics"]) == set(harness.load_cell(cell).end_to_end)
     gets = r["checks"]["gets_differ"]["of"]
-    assert (gets > 0) == ("get_p95_ms" in r["metrics"])
-    assert (r["attempted"] - gets) % 32 == 0  # whole trees of 32 files
+    assert (gets > 0) == _gets(cell) == ("get_p95_ms" in r["metrics"])
+    c = tiny_cell(cell)
+    tree = len(Traffic(c.config, c.traffic, 0).version(0))
+    assert (r["attempted"] - gets) % tree == 0  # whole trees
 
 
 @pytest.mark.parametrize("cell,fault", [
     (c, f) for c in CELLS for f in sorted(CAUGHT_BY)
-    if not (f == "get" and c == "file-backup.first")])
+    if f != "get" or _gets(c)])
 def test_fault_is_caught(cell, fault):
     r = run_tiny(cell, fault)
     assert not r["correct"]

@@ -5,6 +5,8 @@ import os
 import shutil
 import subprocess
 import sys
+import tempfile
+import time
 
 import pytest
 
@@ -59,3 +61,28 @@ def test_fails_in_a_checkout_of_the_benchmark_alone(tmp_path):
                        capture_output=True, text=True, timeout=300)
     assert p.returncode != 0
     assert '"correct"' not in p.stdout
+
+
+@pytest.mark.parametrize("trace,seconds", [(False, 1.0), (True, 0.0)])
+def test_a_traced_window_is_one_version(monkeypatch, trace, seconds):
+    """The untraced window runs for the seconds asked; the traced one for
+    one whole version, whose trace a run has time to export and read."""
+    import tiny
+
+    asked = []
+    drive = harness.drive
+
+    def record(svc, groups, secs, *a):
+        asked.append(secs)
+        return drive(svc, groups, secs, *a)
+
+    monkeypatch.setattr(harness, "drive", record)
+    monkeypatch.setattr(harness, "warm_up", lambda params, sizes: 0)
+    cell = tiny.tiny_cell("file-backup.first")
+    with tempfile.TemporaryDirectory() as work:
+        r = harness.run_cell(cell, 9, 1.0, trace, time.perf_counter(), work,
+                             harness.CompileCounter(), None,
+                             log=lambda s: None)
+    assert asked == [seconds] and r["correct"], r["checks"]
+    if trace:
+        assert r["attempted"] == cell.config["files"]

@@ -190,3 +190,23 @@ def test_recorded_trace_names_stages_and_spans():
     assert r["idle_under"]["phase.commit"] >= r["idle_spans"][
         "commit.object"] > 0
     assert r["idle_spans"]["sched.dispatch"] > 0
+
+
+@pytest.mark.parametrize("path", [OLD, NEW])
+def test_run_record_reads_the_trace_once_for_both_reductions(path):
+    import harness
+
+    rec = harness.reduce_trace(path)
+    ref = tracefile.reduce(tracefile.read(path))
+    named = timeline.reduce(timeline.parse(path))
+    assert set(rec) == set(ref) | {"stages", "idle_spans", "idle_under"}
+    for key in ("busy_s", "window_s", "devices"):
+        assert rec[key] == pytest.approx(ref[key], rel=1e-12)
+    for key in ("device_ops", "idle_gaps"):
+        assert [n for n, _ in rec[key]] == [n for n, _ in ref[key]]
+        assert [t for _, t in rec[key]] == pytest.approx(
+            [t for _, t in ref[key]], rel=1e-9)
+    for key in ("stages", "idle_spans", "idle_under"):
+        assert rec[key] == named[key]
+    assert sum(rec["stages"].values()) == pytest.approx(rec["busy_s"],
+                                                         rel=1e-9)
